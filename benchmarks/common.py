@@ -35,6 +35,19 @@ def time_s(fn: Callable[[], object], reps: int = REPS,
     return float(np.median(out))
 
 
+def refuse_child_on_tpu(what: str) -> None:
+    """Raise when this process sees a TPU.
+
+    `what` measures in a child process on fake CPU devices.  A chip belongs
+    to one process: this one holds it, so a child could only fail, hang, or
+    quietly measure the CPU.  On a TPU host these phases are refused."""
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{what} runs in a child process on fake CPU devices, but this "
+            f"process holds the TPU: the child would not measure the chip. "
+            f"Run it on a CPU host (JAX_PLATFORMS=cpu).")
+
+
 class Csv:
     """Collects `name,us_per_call,derived` rows (benchmarks/run.py format)."""
 

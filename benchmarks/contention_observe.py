@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import Csv
+from benchmarks.common import Csv, refuse_child_on_tpu
 from repro import atomics
 
 RESULT_PATH = os.path.join(os.path.dirname(__file__), "results",
@@ -79,7 +79,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import atomics
 
 FAST = %(fast)r
-mesh = jax.make_mesh((2, 4), ("pod", "dev"))
+from repro.sharding import make_mesh
+mesh = make_mesh((2, 4), ("pod", "dev"))
 m = 4096
 n = 1024 if FAST else 4096
 
@@ -354,6 +355,7 @@ def run(csv: Csv, fast: bool = False, out_path: str = RESULT_PATH
 
     local_ident = _bit_identity_local()
 
+    refuse_child_on_tpu("benchmarks.contention_observe")
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
     env.pop("XLA_FLAGS", None)

@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import Csv
+from benchmarks.common import Csv, refuse_child_on_tpu
 from repro import telemetry
 
 RESULT_PATH = os.path.join(os.path.dirname(__file__), "results",
@@ -55,7 +55,8 @@ import numpy as np
 from repro import atomics
 from repro.atomics import Cas, execute_until
 
-mesh = jax.make_mesh((2, 4), ("pod", "dev"))
+from repro.sharding import make_mesh
+mesh = make_mesh((2, 4), ("pod", "dev"))
 P = jax.sharding.PartitionSpec
 n = 16
 
@@ -213,7 +214,8 @@ def _sharded_row(csv: Csv, fast: bool) -> Dict:
         from repro import atomics
         from repro.atomics import execute_until
         n = 16
-        mesh = jax.make_mesh((1,), ("dev",))
+        from repro.sharding import make_mesh
+        mesh = make_mesh((1,), ("dev",))
         data = jax.device_put(
             jnp.zeros((32,), jnp.int32),
             jax.sharding.NamedSharding(mesh,
@@ -228,6 +230,7 @@ def _sharded_row(csv: Csv, fast: bool) -> Dict:
                "seconds": sp.wall_s,
                "mesh": "1-device (fast)"}
     else:
+        refuse_child_on_tpu("benchmarks.fault_recovery")
         env = dict(os.environ)
         env["PYTHONPATH"] = os.pathsep.join(
             [os.path.join(os.path.dirname(__file__), "..", "src")] +

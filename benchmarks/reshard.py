@@ -41,7 +41,7 @@ import subprocess
 import sys
 from typing import Dict
 
-from benchmarks.common import Csv
+from benchmarks.common import Csv, refuse_child_on_tpu
 
 RESULT_PATH = os.path.join(os.path.dirname(__file__), "results",
                            "reshard.json")
@@ -151,7 +151,8 @@ for m in GRID_M:
     })
 
 # --- cell 2: same-fleet layout change (in-collective exchange path) -------
-mesh24 = jax.make_mesh((2, 4), ("pod", "dev"))
+from repro.sharding import make_mesh
+mesh24 = make_mesh((2, 4), ("pod", "dev"))
 for m in GRID_M:
     hist = history_for(mesh24, m)
     tab0 = jnp.zeros((m,), jnp.int32)
@@ -189,6 +190,7 @@ def run(csv: Csv, fast: bool = False, out_path: str = RESULT_PATH
     if fast and out_path == RESULT_PATH:
         # never clobber the committed full-grid table with a CI smoke run
         out_path = RESULT_PATH.replace(".json", "_fast.json")
+    refuse_child_on_tpu("benchmarks.reshard")
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
     env.pop("XLA_FLAGS", None)

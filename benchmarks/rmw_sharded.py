@@ -31,7 +31,7 @@ import subprocess
 import sys
 from typing import Dict
 
-from benchmarks.common import Csv
+from benchmarks.common import Csv, refuse_child_on_tpu
 
 RESULT_PATH = os.path.join(os.path.dirname(__file__), "results",
                            "rmw_sharded.json")
@@ -46,16 +46,13 @@ from jax.sharding import PartitionSpec as P
 from repro import atomics
 
 FAST = %(fast)r
-mesh = jax.make_mesh((2, 4), ("pod", "dev"))
+from repro.sharding import make_mesh
+mesh = make_mesh((2, 4), ("pod", "dev"))
 NDEV = 8
 
 def shard_map(fn, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 def median_time(fn, args, reps=5, warmup=2):
     for _ in range(warmup):
@@ -133,6 +130,7 @@ def run(csv: Csv, fast: bool = False, out_path: str = RESULT_PATH
     if fast and out_path == RESULT_PATH:
         # never clobber the committed full-grid table with a CI smoke run
         out_path = RESULT_PATH.replace(".json", "_fast.json")
+    refuse_child_on_tpu("benchmarks.rmw_sharded")
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
     env.pop("XLA_FLAGS", None)

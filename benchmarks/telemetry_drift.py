@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import Csv
+from benchmarks.common import Csv, refuse_child_on_tpu
 from repro import atomics, telemetry
 from repro.telemetry import drift as drift_lib
 
@@ -64,7 +64,8 @@ from repro import atomics, telemetry
 
 FAST = %(fast)r
 OUT = %(out)r
-mesh = jax.make_mesh((2, 4), ("pod", "dev"))
+from repro.sharding import make_mesh
+mesh = make_mesh((2, 4), ("pod", "dev"))
 m = 4096
 
 def table():
@@ -247,6 +248,7 @@ def run(csv: Csv, fast: bool = False, out_path: str = RESULT_PATH
 
     _local_capture(local_cap, fast)
 
+    refuse_child_on_tpu("benchmarks.telemetry_drift")
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
     env.pop("XLA_FLAGS", None)

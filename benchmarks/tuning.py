@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import Csv
+from benchmarks.common import Csv, refuse_child_on_tpu
 from repro import atomics, telemetry
 from repro.core import rmw_engine
 from repro.runtime.chaos import FaultPlan, SiteSpec
@@ -322,7 +322,8 @@ from repro import atomics
 from repro.tuning import SpecController, TuningConfig
 
 TUNED = %(tuned)r
-mesh = jax.make_mesh((2, 4), ("pod", "dev"))
+from repro.sharding import make_mesh
+mesh = make_mesh((2, 4), ("pod", "dev"))
 m = 512
 
 def table():
@@ -385,6 +386,7 @@ print("RESULT:" + json.dumps({
 
 
 def _bit_identity_sharded() -> Dict[str, object]:
+    refuse_child_on_tpu("benchmarks.tuning")
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
     env.pop("XLA_FLAGS", None)

@@ -30,8 +30,8 @@ from repro.core.bfs import bfs, bfs_sharded, kronecker_graph  # noqa: E402
 from repro.core.rmw import rmw_serialized                     # noqa: E402
 from repro.core.rmw_sharded import MeshAxis, select_exchange  # noqa: E402
 from repro.core.placement import Tier                         # noqa: E402
-from repro.sharding import (DEFAULT_RULES, shard_map_compat,  # noqa: E402
-                            use_mesh)
+from repro.sharding import (DEFAULT_RULES, make_mesh,  # noqa: E402
+                            shard_map_compat, use_mesh)
 
 
 def main() -> None:
@@ -41,7 +41,7 @@ def main() -> None:
     args = ap.parse_args()
 
     ndev = jax.device_count()
-    mesh = jax.make_mesh((2, ndev // 2), ("pod", "model"))
+    mesh = make_mesh((2, ndev // 2), ("pod", "model"))
     n, m = args.n_per_device, args.table
     rng = np.random.default_rng(0)
     # 95% of every device's ops hit 8 slots of shard 0 — the hot line

@@ -188,9 +188,9 @@ def check_examples_sharded() -> List[Finding]:
     from jax.sharding import PartitionSpec as P
 
     from repro import atomics
-    from repro.sharding import shard_map_compat
+    from repro.sharding import make_mesh, shard_map_compat
 
-    mesh = jax.make_mesh((1,), ("dev",))
+    mesh = make_mesh((1,), ("dev",))
     spec = P("dev")
 
     def fn(t, i, v):

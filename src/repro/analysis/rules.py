@@ -38,10 +38,13 @@ channel; no execution.
 
 from __future__ import annotations
 
+import os
+import sysconfig
 from typing import Any, Dict, List, Optional, Set, Tuple
 
+import jax
+import jaxlib
 import numpy as np
-
 from jax.extend.core import Literal, Var
 
 from repro.atomics import contracts
@@ -49,10 +52,23 @@ from repro.atomics.ops import OP_KINDS
 from repro.analysis.findings import Finding, make_finding
 from repro.analysis.trace import CallSite, TraceResult
 
-try:
-    from jax._src import source_info_util as _siu
-except Exception:  # noqa: BLE001 — provenance degrades, rules still run
-    _siu = None
+#: frames under these directories are JAX's own, never the user code a
+#: finding should point at
+_JAX_DIRS = tuple(os.path.dirname(m.__file__) + os.sep for m in (jax, jaxlib))
+_STDLIB_DIR = sysconfig.get_paths()["stdlib"] + os.sep
+#: installed packages (this one too, when it is not an editable install)
+#: sit under the stdlib prefix on a standard layout: user code all the same
+_PACKAGE_PARTS = (os.sep + "site-packages" + os.sep,
+                  os.sep + "dist-packages" + os.sep)
+
+
+def _is_machinery(file_name: str) -> bool:
+    """True for a frame in JAX or in the standard library proper."""
+    if file_name.startswith(_JAX_DIRS):
+        return True
+    return (file_name.startswith(_STDLIB_DIR)
+            and not any(p in file_name for p in _PACKAGE_PARTS))
+
 
 #: scatter-family primitive names (set-style "scatter" is the
 #: undefined-ordering one; add/mul/min/max are duplicate-commutative)
@@ -67,22 +83,20 @@ _TRANSPARENT = ("convert_element_type", "broadcast_in_dim", "reshape",
 
 
 def _frames(eqn) -> List[Any]:
-    if _siu is None:
-        return []
+    """The equation's user frames, innermost first (JAX and stdlib frames
+    dropped)."""
     si = getattr(eqn, "source_info", None)
-    if si is None:
+    tb = getattr(si, "traceback", None)
+    if tb is None:
         return []
-    try:
-        return list(_siu.user_frames(si))
-    except Exception:  # noqa: BLE001
-        return []
+    return [fr for fr in tb.frames if not _is_machinery(fr.file_name)]
 
 
 def _sanctioned(eqn) -> bool:
     """True when any user frame of the equation lives in a sanctioned RMW
     module — the scatter is the engine's own, not a bypass."""
     for fr in _frames(eqn):
-        fname = getattr(fr, "file_name", "").replace("\\", "/")
+        fname = fr.file_name.replace("\\", "/")
         if any(p in fname for p in contracts.SANCTIONED_PATHS):
             return True
     return False
@@ -93,7 +107,7 @@ def _loc(eqn) -> Tuple[Optional[str], Optional[int]]:
     if not frames:
         return None, None
     fr = frames[0]                       # innermost user frame
-    return getattr(fr, "file_name", None), getattr(fr, "start_line", None)
+    return fr.file_name, fr.line_num
 
 
 class _Ctx:
@@ -322,7 +336,7 @@ def _walk(ctx: _Ctx, jaxpr_like) -> None:
         if name == "while":
             _rule_a003(ctx, eqn)
 
-        don = eqn.params.get("donated_invars") if name == "pjit" else None
+        don = eqn.params.get("donated_invars") if name == "jit" else None
         if don and any(don):
             _rule_a004(ctx, eqn, don, last_use, i, n_eqns)
 
@@ -510,7 +524,7 @@ def _rule_a004(ctx: _Ctx, eqn, donated, last_use, idx: int,
                      f"buffer donated to a jitted call (donate_argnums) is "
                      f"still read by {how} — after donation the buffer may "
                      f"alias the callee's output; keep a copy or drop the "
-                     f"donation", eqn=eqn, provenance="pjit donated_invars")
+                     f"donation", eqn=eqn, provenance="jit donated_invars")
 
 
 # ---------------------------------------------------------------------------

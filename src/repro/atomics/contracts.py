@@ -93,7 +93,7 @@ _site_counter = 0
 def _get_marker():
     global _marker_p
     if _marker_p is None:
-        from jax._src.core import Primitive
+        from jax.extend.core import Primitive
         from jax.interpreters import ad, batching, mlir
 
         p = Primitive(MARKER)

@@ -186,10 +186,6 @@ def _dispatch_one(table: AtomicTable, op: AtomicOp, *, need_fetched: bool,
 # Telemetry: one decision event per executed op batch
 # ---------------------------------------------------------------------------
 
-#: prebound — ``jax.core.Tracer`` goes through the deprecation-module
-#: ``__getattr__`` on every lookup, measurable on the eager hot path
-_TRACER = jax.core.Tracer
-
 
 def _decision_fields(table: AtomicTable, op: AtomicOp, *, need_fetched: bool,
                      backend: str, strategy: str, spec,
@@ -334,7 +330,8 @@ def _execute_one(table: AtomicTable, op: AtomicOp, *, need_fetched: bool,
                 _DECISION_CACHE.clear()
             _DECISION_CACHE[key] = fields
         fields = dict(fields)        # the cached template stays pristine
-    traced = isinstance(data, _TRACER) or isinstance(op.indices, _TRACER)
+    traced = isinstance(data, jax.core.Tracer) \
+        or isinstance(op.indices, jax.core.Tracer)
     # _tcore flag reads instead of the telemetry.*_enabled() accessors:
     # each saved call is ~0.15us against the overhead budget
     if traced or not _tcore._sync:

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import atomics
+from repro.sharding import make_mesh, shard_map_compat
 
 Array = jax.Array
 
@@ -156,7 +157,7 @@ def bfs_sharded(src: np.ndarray, dst: np.ndarray, n: int, root: int = 0,
         raise ValueError(f"bfs_sharded supports op 'cas' or 'swp', "
                          f"got {op!r}")
     if mesh is None:
-        mesh = jax.make_mesh((jax.device_count(),), (axis,))
+        mesh = make_mesh((jax.device_count(),), (axis,))
     ndev = int(mesh.shape[axis])
     n_pad = -(-n // ndev) * ndev
     e_pad = -(-len(src) // ndev) * ndev
@@ -202,7 +203,6 @@ def bfs_sharded(src: np.ndarray, dst: np.ndarray, n: int, root: int = 0,
                          jnp.array(True)))
         return parent, lvl[None], edges[None]
 
-    from repro.sharding import shard_map_compat
     mapped = shard_map_compat(shard_fn, mesh,
                               (P(axis), P(axis), P(axis), P(axis)),
                               (P(axis), P(axis), P(axis)))
@@ -214,14 +214,21 @@ def bfs_sharded(src: np.ndarray, dst: np.ndarray, n: int, root: int = 0,
 
 def validate_parents(src: np.ndarray, dst: np.ndarray, parent: np.ndarray,
                      root: int) -> bool:
-    """Every reached vertex's parent edge must exist; root is its own parent."""
-    parent = np.asarray(parent)
+    """Every reached vertex's parent edge must exist; root is its own parent.
+
+    Vectorized: edges and claimed parent edges are encoded as int64 keys
+    ``u * n + v`` and matched with `np.isin` (a Graph500-scale edge list
+    does not fit a Python set)."""
+    parent = np.asarray(parent, np.int64)
     if parent[root] != root:
         return False
-    edges = set(zip(np.asarray(src).tolist(), np.asarray(dst).tolist()))
-    for v in np.nonzero(parent >= 0)[0]:
-        if v == root:
-            continue
-        if (int(parent[v]), int(v)) not in edges:
-            return False
-    return True
+    src = np.asarray(src, np.int64)
+    dst = np.asarray(dst, np.int64)
+    n = np.int64(max(parent.shape[0], int(src.max(initial=0)) + 1,
+                     int(dst.max(initial=0)) + 1))
+    reached = np.nonzero(parent >= 0)[0]
+    reached = reached[reached != root]
+    if parent[reached].max(initial=0) >= n:
+        return False
+    return bool(np.isin(parent[reached] * n + reached,
+                        src * n + dst).all())

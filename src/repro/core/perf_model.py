@@ -133,6 +133,24 @@ TPU_V5E = HardwareSpec(
     device_put_launch_s=5e-6,
 )
 
+#: TPU specs by `jax.Device.device_kind`.  The v5e row's peaks are Google
+#: Cloud's published figures ("TPU v5e" documentation: 197 TFLOP/s bf16,
+#: 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip); its latencies and
+#: selection constants are modelled, not measured.
+TPU_SPECS: Dict[str, HardwareSpec] = {"TPU v5 lite": TPU_V5E}
+
+
+def tpu_spec(device_kind: str) -> HardwareSpec:
+    """The spec of a TPU by its `device_kind`.  A kind not in `TPU_SPECS`
+    is an error: another chip's constants would mis-steer every selector."""
+    try:
+        return TPU_SPECS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no HardwareSpec for TPU device_kind {device_kind!r}; known: "
+            f"{sorted(TPU_SPECS)} (add a row with its published "
+            f"source to perf_model.TPU_SPECS)") from None
+
 
 def cpu_default_spec() -> HardwareSpec:
     """Uncalibrated CPU spec (order-of-magnitude priors; benchmarks calibrate it)."""

@@ -23,7 +23,8 @@ TPUs execute poorly.  This module turns RMW execution into a pluggable
 ``pallas``
     The Mosaic one-hot-matmul kernel (`kernels.rmw.ops.rmw_apply_fetched`);
     table tiles stay VMEM-resident across the index-block grid axis.  fp32
-    tables only.
+    tables only; the fetched path (and CAS) up to
+    ``PALLAS_FETCHED_MAX_SLOTS`` slots.
 
 Every backend produces results bit-identical to ``rmw_serialized`` for every
 op it supports (integer dtypes; float FAA is exact up to reassociation, the
@@ -330,10 +331,17 @@ class RmwBackend:
     cost: Callable[..., float]          # (spec, op, n, m, need_fetched)
     general_cas: bool = False           # per-op expected arrays supported?
     float_table_only: bool = False      # e.g. the fp32 Pallas kernel
+    #: largest table its fetched path (CAS always fetches) accepts, or None
+    max_fetched_slots: Optional[int] = None
 
     def supports(self, op: str, *, uniform_expected: bool = True,
-                 dtype=None) -> bool:
+                 dtype=None, m: Optional[int] = None,
+                 need_fetched: bool = True) -> bool:
         if op not in self.ops:
+            return False
+        if self.max_fetched_slots is not None and m is not None \
+                and (need_fetched or op == "cas") \
+                and m > self.max_fetched_slots:
             return False
         if op == "cas" and not uniform_expected and not self.general_cas:
             return False
@@ -341,6 +349,12 @@ class RmwBackend:
                 and not jnp.issubdtype(dtype, jnp.floating):
             return False
         return True
+
+
+#: the Pallas fetched kernel compiles one program per 512-slot table tile
+#: (`kernels.rmw.kernel.rmw_table_fetched`): 32 tiles take ~3 s of TPU
+#: compile, and the count grows linearly, so larger tables are refused
+PALLAS_FETCHED_MAX_SLOTS = 1 << 14
 
 
 def _run_pallas(table, indices, values, op, expected=None,
@@ -378,7 +392,8 @@ register_backend(RmwBackend(
     cost=cost_onehot))
 register_backend(RmwBackend(
     name="pallas", ops=frozenset(("faa", "min", "max", "swp", "cas")),
-    run=_run_pallas, cost=cost_pallas, float_table_only=True))
+    run=_run_pallas, cost=cost_pallas, float_table_only=True,
+    max_fetched_slots=PALLAS_FETCHED_MAX_SLOTS))
 
 
 def calibrated_spec_path() -> str:
@@ -450,15 +465,16 @@ def spec_epoch() -> int:
 
 
 def calibrated_spec() -> perf_model.HardwareSpec:
-    """Platform spec ignoring any live-tuned override: TPU constants on TPU;
-    on CPU the calibrated spec from `benchmarks/calibrate.py` when present
-    (falling back to the priors).  This is the envelope anchor the tuning
+    """Platform spec ignoring any live-tuned override: on TPU the row of
+    `perf_model.TPU_SPECS` for the device's `device_kind` (an unknown kind
+    raises); on CPU the calibrated spec from `benchmarks/calibrate.py` when
+    present (falling back to the priors).  This is the envelope anchor the tuning
     controller validates live proposals against."""
     backend = jax.default_backend()
     if backend in _SPEC_CACHE:
         return _SPEC_CACHE[backend]
     if backend == "tpu":
-        spec = perf_model.TPU_V5E
+        spec = perf_model.tpu_spec(jax.devices()[0].device_kind)
     else:
         spec = perf_model.cpu_default_spec()
         path = calibrated_spec_path()
@@ -507,7 +523,7 @@ def select_backend_with_cost(op: str, n: int, m: int,
     costs = {b.name: b.cost(spec, op, n, m, need_fetched)
              for b in BACKENDS.values()
              if b.supports(op, uniform_expected=uniform_expected,
-                           dtype=dtype)}
+                           dtype=dtype, m=m, need_fetched=need_fetched)}
     choice = min(costs, key=costs.get)
     return Selection(choice, costs[choice], costs)
 
@@ -557,6 +573,11 @@ def execute_backend(table: Array, indices: Array, values: Array, op: str,
     except KeyError:
         raise ValueError(f"unknown backend {backend!r}; "
                          f"have {sorted(BACKENDS)}") from None
+    if not b.supports(op, m=int(table.shape[0]), need_fetched=need_fetched):
+        raise ValueError(
+            f"backend {b.name!r} does not run {op!r} on a "
+            f"{int(table.shape[0])}-slot table with need_fetched="
+            f"{need_fetched}")
     if op == "cas" and not b.general_cas \
             and not _is_uniform_expected(expected):
         raise ValueError(

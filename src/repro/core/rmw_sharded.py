@@ -738,7 +738,8 @@ def _mesh_axes(names: Sequence[str], sizes: Sequence[int],
 def _cost_engine(spec, op: str, n: int, m: int, need_fetched: bool) -> float:
     """Cheapest local-backend prediction — phase-1/phase-2 engine passes."""
     cands = [b for b in rmw_engine.BACKENDS.values()
-             if b.supports(op, uniform_expected=True)]
+             if b.supports(op, uniform_expected=True, m=m,
+                           need_fetched=need_fetched)]
     return min(b.cost(spec, op, max(n, 1), max(m, 1), need_fetched)
                for b in cands)
 

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 
@@ -87,11 +89,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_k: int = DEFAULT_BLOCK_K,
                     kv_valid: int | None = None,
                     kv_offset: int | None = None,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q: (B, Hq, Sq, D), k/v: (B, Hkv, Skv, D); Sq % block_q == 0,
     Skv % block_k == 0 (ops.py pads).  ``kv_valid`` masks trailing padded kv
     rows; ``kv_offset`` is the causal diagonal shift (real_skv - real_sq).
-    Returns (B, Hq, Sq, D)."""
+    Returns (B, Hq, Sq, D).  ``interpret=None`` compiles on TPU and
+    interprets elsewhere."""
     b, hq, sq, d = q.shape
     hkv, skv = k.shape[1], k.shape[2]
     assert hq % hkv == 0, (hq, hkv)
@@ -135,6 +138,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running normalizer
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(b, hq, sq, d)

@@ -13,10 +13,6 @@ from repro.kernels.flash_attention import ref as _ref
 Array = jax.Array
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
                                              "block_k", "use_kernel"))
 def attention(q: Array, k: Array, v: Array, *, causal: bool = True,
@@ -43,6 +39,5 @@ def attention(q: Array, k: Array, v: Array, *, causal: bool = True,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     out = _k.flash_attention(q, k, v, causal=causal, scale=scale,
                              block_q=block_q, block_k=block_k,
-                             kv_valid=skv, kv_offset=kv_offset,
-                             interpret=not _on_tpu())
+                             kv_valid=skv, kv_offset=kv_offset)
     return out[:, :, :sq, :]

@@ -30,8 +30,7 @@ output block is ever revisited non-consecutively (the only revisit is the
 tile accumulator along the single grid axis — the reduction pattern
 compiled Pallas TPU guarantees).
 
-``interpret`` now defaults to auto (`None` -> compiled on TPU, interpreter
-elsewhere) instead of the old hardcoded ``True``.
+``interpret=None`` (the default) compiles on TPU and interprets elsewhere.
 
 Alignment: TABLE_TILE is a multiple of 128 (lane width) — the benchmark
 `benchmarks/unaligned.py` measures the penalty of violating this, the TPU
@@ -47,13 +46,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 DEFAULT_TABLE_TILE = 512      # table slots per tile (multiple of 128)
 DEFAULT_BLOCK = 1024          # index/value elements per block
-
-
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    """Auto-select the Pallas interpreter off-TPU (old default: always True)."""
-    return jax.default_backend() != "tpu" if interpret is None else interpret
+#: every one-hot contraction is exact in float32: one bf16 MXU pass would
+#: round table values and sums past 2^8
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _rmw_kernel(idx_ref, val_ref, table_ref, out_ref, *, op: str,
@@ -77,7 +76,8 @@ def _rmw_kernel(idx_ref, val_ref, table_ref, out_ref, *, op: str,
     if op == "faa":
         # MXU path: (1, block) @ (block, tile) — the combining reduction.
         upd = jnp.dot(val, one_hot.astype(val.dtype),
-                      preferred_element_type=jnp.float32)
+                      preferred_element_type=jnp.float32,
+                      precision=_EXACT)
         out_ref[...] = acc + upd.astype(acc.dtype)
     elif op in ("min", "max"):
         neutral = (jnp.asarray(jnp.finfo(val.dtype).max, val.dtype) if op == "min"
@@ -96,7 +96,8 @@ def _rmw_kernel(idx_ref, val_ref, table_ref, out_ref, *, op: str,
         # gather the winning value via a second one-hot contraction
         sel = (masked_pos == best) & one_hot & (best >= 0)
         winner = jnp.dot(val, sel.astype(val.dtype),
-                         preferred_element_type=jnp.float32)
+                         preferred_element_type=jnp.float32,
+                         precision=_EXACT)
         out_ref[...] = jnp.where(best >= 0, winner.astype(acc.dtype), acc)
     else:
         raise ValueError(f"unknown op {op!r}")
@@ -132,7 +133,7 @@ def rmw_table(table: jax.Array, indices: jax.Array, values: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, table_tile), lambda t, b: (0, t)),
         out_shape=jax.ShapeDtypeStruct((1, n), table.dtype),
-        interpret=_resolve_interpret(interpret),
+        interpret=resolve_interpret(interpret),
     )(indices.reshape(1, nb), values.reshape(1, nb), table.reshape(1, n))
     return out.reshape(n)
 
@@ -188,7 +189,7 @@ def slot_counts(indices: jax.Array, m: int, *,
         in_specs=[pl.BlockSpec((1, block), lambda t, b: (0, b))],
         out_specs=pl.BlockSpec((1, table_tile), lambda t, b: (0, t)),
         out_shape=jax.ShapeDtypeStruct((1, m), jnp.int32),
-        interpret=_resolve_interpret(interpret),
+        interpret=resolve_interpret(interpret),
     )(indices.reshape(1, nb))
     return out.reshape(m)
 
@@ -222,8 +223,8 @@ def _rmw_fetched_kernel(idx_ref, val_ref, table_ref, exp_ref, out_ref,
     acc = out_ref[...]                              # tile BEFORE this block
     ohf = one_hot.astype(val.dtype)
     # base[i] = acc[idx[i]] — gather as a one-hot contraction (MXU)
-    base = jnp.dot(acc, ohf.T, preferred_element_type=jnp.float32
-                   ).astype(val.dtype)              # (1, block)
+    base = jnp.dot(acc, ohf.T, preferred_element_type=jnp.float32,
+                   precision=_EXACT).astype(val.dtype)   # (1, block)
 
     pos_i = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
     pos_j = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
@@ -235,10 +236,12 @@ def _rmw_fetched_kernel(idx_ref, val_ref, table_ref, exp_ref, out_ref,
     if op == "faa":
         # exclusive per-slot prefix: the lower-triangular-masked one-hot matmul
         prefix = jnp.dot(val, same.astype(val.dtype).T,
-                         preferred_element_type=jnp.float32).astype(val.dtype)
+                         preferred_element_type=jnp.float32,
+                         precision=_EXACT).astype(val.dtype)
         fetched = base + prefix
         ok = ones
-        upd = jnp.dot(val, ohf, preferred_element_type=jnp.float32)
+        upd = jnp.dot(val, ohf, preferred_element_type=jnp.float32,
+                      precision=_EXACT)
         out_ref[...] = acc + upd.astype(acc.dtype)
     elif op in ("min", "max"):
         neutral = (jnp.asarray(jnp.finfo(val.dtype).max, val.dtype)
@@ -258,7 +261,8 @@ def _rmw_fetched_kernel(idx_ref, val_ref, table_ref, exp_ref, out_ref,
         mpos = jnp.where(same, pos_j, -1).max(axis=1).reshape(1, block)
         sel = same & (pos_j == mpos.reshape(block, 1))
         prev = jnp.dot(val, sel.astype(val.dtype).T,
-                       preferred_element_type=jnp.float32).astype(val.dtype)
+                       preferred_element_type=jnp.float32,
+                       precision=_EXACT).astype(val.dtype)
         fetched = jnp.where(mpos >= 0, prev, base)
         ok = ones
         gpos = jax.lax.broadcasted_iota(jnp.int32, (block, table_tile), 0) \
@@ -267,30 +271,34 @@ def _rmw_fetched_kernel(idx_ref, val_ref, table_ref, exp_ref, out_ref,
         best = jnp.max(masked_pos, axis=0).reshape(1, table_tile)
         wsel = (masked_pos == best) & one_hot & (best >= 0)
         winner = jnp.dot(val, wsel.astype(val.dtype),
-                         preferred_element_type=jnp.float32)
+                         preferred_element_type=jnp.float32,
+                         precision=_EXACT)
         out_ref[...] = jnp.where(best >= 0, winner.astype(acc.dtype), acc)
     else:  # cas (uniform expected): first value != expected wins a live slot
         e = exp_ref[0, 0].astype(val.dtype)
         ne = val != e                                              # (1, B)
+        # column form from the values, not from `ne`: Mosaic cannot
+        # reshape a boolean vector (1, B) -> (B, 1)
+        ne_col = val.reshape(block, 1) != e                        # (B, 1)
         big = jnp.int32(block)
-        fpos = jnp.where(same & ne.reshape(1, block), pos_j, big
+        fpos = jnp.where(same & ne, pos_j, big
                          ).min(axis=1).reshape(1, block)
-        xsel = same & ne.reshape(1, block) \
-            & (pos_j == fpos.reshape(block, 1))
+        xsel = same & ne & (pos_j == fpos.reshape(block, 1))
         xval = jnp.dot(val, xsel.astype(val.dtype).T,
-                       preferred_element_type=jnp.float32).astype(val.dtype)
+                       preferred_element_type=jnp.float32,
+                       precision=_EXACT).astype(val.dtype)
         x_excl = jnp.where(fpos < big, xval, e)
         v_before = jnp.where(base == e, x_excl, base)
         fetched = v_before
         ok = (v_before == e).astype(val.dtype)
         # tile update: per slot, the first op with value != expected
         opos = jax.lax.broadcasted_iota(jnp.int32, (block, table_tile), 0)
-        fslot = jnp.where(one_hot & ne.reshape(block, 1), opos, big
+        fslot = jnp.where(one_hot & ne_col, opos, big
                           ).min(axis=0).reshape(1, table_tile)
-        fsel = one_hot & (opos == fslot.reshape(1, table_tile)) \
-            & ne.reshape(block, 1)
+        fsel = one_hot & (opos == fslot) & ne_col
         first_val = jnp.dot(val, fsel.astype(val.dtype),
-                            preferred_element_type=jnp.float32
+                            preferred_element_type=jnp.float32,
+                            precision=_EXACT
                             ).astype(acc.dtype)
         out_ref[...] = jnp.where((acc == e) & (fslot < big), first_val, acc)
 
@@ -328,7 +336,7 @@ def rmw_table_fetched(table: jax.Array, indices: jax.Array,
     assert nb % block == 0, (nb, block)
     if op == "cas" and expected is None:
         raise ValueError("cas requires `expected`")
-    interp = _resolve_interpret(interpret)
+    interp = resolve_interpret(interpret)
     exp = jnp.full((1, 1), 0 if expected is None else expected, table.dtype)
     idx2 = indices.reshape(1, nb)
     val2 = values.reshape(1, nb)

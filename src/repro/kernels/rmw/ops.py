@@ -1,10 +1,9 @@
 """jit'd public wrappers for the combining-RMW kernel.
 
-Handles padding (table to the tile multiple, batch to the block multiple),
-dtype management, and platform dispatch: on TPU the Mosaic kernel runs
-compiled; elsewhere ``interpret`` (auto-selected, no longer hardcoded)
-executes the same kernel body — the validation mode used by this container's
-tests/benchmarks.
+Handles padding (table to the tile multiple, batch to the block multiple)
+and dtype management.  The kernels pick their mode from the platform: on
+TPU the Mosaic kernel runs compiled; elsewhere the Pallas interpreter
+executes the same kernel body (the CPU tests' validation mode).
 
 `rmw_apply` returns the updated table only; `rmw_apply_fetched` additionally
 returns per-op serialized-order fetch results and CAS success flags — this is
@@ -24,10 +23,6 @@ from repro.kernels.rmw import kernel as _k
 from repro.kernels.rmw import ref as _ref
 
 Array = jax.Array
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_to(x: Array, multiple: int, fill) -> Array:
@@ -57,7 +52,7 @@ def rmw_apply(table: Array, indices: Array, values: Array, op: str = "faa",
     idx_p = _pad_to(indices.astype(jnp.int32), block, jnp.int32(tab_p.shape[0]))
     val_p = _pad_to(values, block, 0)
     out = _k.rmw_table(tab_p, idx_p, val_p, op, table_tile=table_tile,
-                       block=block, interpret=not _on_tpu())
+                       block=block)
     return out[:n]
 
 
@@ -84,7 +79,7 @@ def rmw_apply_fetched(table: Array, indices: Array, values: Array,
     val_p = _pad_to(values, block, 0)
     out, fetched, success = _k.rmw_table_fetched(
         tab_p, idx_p, val_p, op, expected=expected, table_tile=table_tile,
-        block=block, interpret=not _on_tpu())
+        block=block)
     return RmwResult(out[:n], fetched[:n_ops], success[:n_ops])
 
 
@@ -110,6 +105,5 @@ def slot_occupancy(indices: Array, m: int, *,
     idx = indices.astype(jnp.int32)
     idx = jnp.where((idx < 0) | (idx >= m), jnp.int32(m_p), idx)
     idx_p = _pad_to(idx, block, jnp.int32(m_p))
-    out = _k.slot_counts(idx_p, m_p, table_tile=tile, block=block,
-                         interpret=not _on_tpu())
+    out = _k.slot_counts(idx_p, m_p, table_tile=tile, block=block)
     return out[:m]

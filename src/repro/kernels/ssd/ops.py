@@ -19,10 +19,6 @@ from repro.kernels.ssd import ref as _ref
 Array = jax.Array
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("chunk", "return_final_state"))
 def ssd_chunked_jnp(x: Array, dt: Array, A: Array, B: Array, C: Array, *,
                     chunk: int = _k.DEFAULT_CHUNK,
@@ -31,7 +27,7 @@ def ssd_chunked_jnp(x: Array, dt: Array, A: Array, B: Array, C: Array, *,
 
     Keeps the head axis explicit throughout so TP sharding of heads over the
     `model` mesh axis propagates (no (B*H) merges that would force gathers).
-    Used for the train path (pallas_call has no vjp) and on CPU.
+    Used on CPU, and as the backward pass of the kernel path.
     """
     b, s, h, p = x.shape
     n = B.shape[-1]
@@ -56,7 +52,9 @@ def ssd_chunked_jnp(x: Array, dt: Array, A: Array, B: Array, C: Array, *,
     ls = l[:, :, None, :, :]                                  # (b,c,1,k,h)
     tpos = jnp.arange(chunk)
     mask = tpos[None, :] <= tpos[:, None]                     # (q,k) s<=t
-    m = jnp.where(mask[None, None, :, :, None], jnp.exp(lt - ls), 0.0)
+    # mask before exp: above the diagonal l_t - l_s > 0 overflows past a
+    # chunk of ~128 steps, and where(mask, inf, 0)'s gradient is 0*inf = NaN
+    m = jnp.exp(jnp.where(mask[None, None, :, :, None], lt - ls, -jnp.inf))
     scores = jnp.einsum("bcqhn,bckhn->bcqkh", Cr, Br)
     y_intra = jnp.einsum("bcqkh,bckhp->bcqhp", scores * m, xdt)
 
@@ -88,12 +86,19 @@ def ssd(x: Array, dt: Array, A: Array, B: Array, C: Array, *,
         return_final_state: bool = False):
     """Chunked SSD.  x (B,S,H,P), dt (B,S,H), A (H,), B/C (B,S,H,N).
     With return_final_state, also returns h_final (B,H,N,P) for decode.
-    use_kernel: None = auto (Pallas kernel on TPU, jnp elsewhere/for grad)."""
+    use_kernel: None = the Pallas kernel on TPU, jnp elsewhere.  The
+    gradient never goes through ``pallas_call``: the kernel path's backward
+    pass is `ssd_chunked_jnp`'s."""
     if use_kernel is None:
-        use_kernel = _on_tpu()
+        use_kernel = jax.default_backend() == "tpu"
     if not use_kernel:
         return ssd_chunked_jnp(x, dt, A, B, C, chunk=chunk,
                                return_final_state=return_final_state)
+    return _ssd_kernel_path(x, dt, A, B, C, chunk, return_final_state)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _ssd_kernel_path(x, dt, A, B, C, chunk, return_final_state):
     b, s, h, p = x.shape
     n = B.shape[-1]
     pad = (-s) % chunk
@@ -115,8 +120,7 @@ def ssd(x: Array, dt: Array, A: Array, B: Array, C: Array, *,
         .astype(jnp.float32)
     Bf, Cf = flat(B).astype(jnp.float32), flat(C).astype(jnp.float32)
 
-    y_intra, states = _k.ssd_chunk(xdt, adt, Bf, Cf, chunk=chunk,
-                                   interpret=not _on_tpu())
+    y_intra, states = _k.ssd_chunk(xdt, adt, Bf, Cf, chunk=chunk)
 
     # cross-chunk recurrence: H_c = exp(Ltot_c) * H_{c-1} + S_c
     l = jnp.cumsum(adt.reshape(b * h, nc, chunk), axis=-1)     # (BH,NC,Q)
@@ -144,6 +148,21 @@ def ssd(x: Array, dt: Array, A: Array, B: Array, C: Array, *,
         h_final = h_incl[:, -1].reshape(b, h, n, p)
         return y, h_final
     return y
+
+
+def _ssd_kernel_path_fwd(x, dt, A, B, C, chunk, return_final_state):
+    out = _ssd_kernel_path(x, dt, A, B, C, chunk, return_final_state)
+    return out, (x, dt, A, B, C)
+
+
+def _ssd_kernel_path_bwd(chunk, return_final_state, res, g):
+    _, vjp = jax.vjp(functools.partial(
+        ssd_chunked_jnp, chunk=chunk,
+        return_final_state=return_final_state), *res)
+    return vjp(g)
+
+
+_ssd_kernel_path.defvjp(_ssd_kernel_path_fwd, _ssd_kernel_path_bwd)
 
 
 def _final_state_ref(x, dt, A, B, C):

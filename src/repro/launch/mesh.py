@@ -3,7 +3,7 @@ never touches jax device state)."""
 
 from __future__ import annotations
 
-import jax
+from repro.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -12,10 +12,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     DCN, `data`/`model` ride ICI."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (possibly fake) local devices exist —
     used by tests that set XLA_FLAGS=--xla_force_host_platform_device_count."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.configs import get_config, get_reduced
 from repro.data.pipeline import batch_kwargs_for
+from repro.launch.cache import setup_compile_cache
 from repro.models.model import build_model
 
 
@@ -100,6 +101,7 @@ class BatchServer:
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma_2b")
     ap.add_argument("--requests", type=int, default=8)

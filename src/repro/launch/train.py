@@ -24,6 +24,7 @@ from repro.checkpoint.ckpt import AsyncCheckpointer
 from repro.configs import get_config, get_reduced
 from repro.data.pipeline import DataConfig, batch_kwargs_for, synthetic_batch
 from repro.launch import shardings as sh
+from repro.launch.cache import setup_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models.model import build_model
 from repro.optim.adamw import AdamWConfig, init_state
@@ -196,6 +197,7 @@ class _null_ctx:
 def main() -> None:
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)

@@ -33,7 +33,7 @@ from repro.atomics.table import AtomicTable
 from repro.checkpoint import ckpt as ckpt_lib
 from repro.launch import shardings as sh
 from repro.models.config import ModelConfig
-from repro.sharding import use_mesh
+from repro.sharding import make_mesh, use_mesh
 
 
 def _is_table(x) -> bool:
@@ -137,4 +137,4 @@ def survivors_mesh(axis_sizes: Dict[str, int], lost_data_shards: int = 0):
     if sizes["data"] < 1:
         raise ValueError("no data shards left")
     names = tuple(sizes)
-    return jax.make_mesh(tuple(sizes[n] for n in names), names)
+    return make_mesh(tuple(sizes[n] for n in names), names)

@@ -18,7 +18,7 @@ import threading
 from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 _state = threading.local()
 
@@ -55,16 +55,24 @@ def active_mesh() -> Optional[Mesh]:
     return _current()[0]
 
 
+def make_mesh(shape: Sequence[int], names: Sequence[str], *,
+              devices=None) -> Mesh:
+    """`jax.make_mesh` with every axis `AxisType.Auto`.
+
+    The model and atomics code place arrays by sharding propagation
+    (`hint`, shard_map in/out specs).  Under `AxisType.Explicit`, the
+    default of `jax.make_mesh`, a gather from a sharded operand (the
+    embedding lookup, indexing a sharded result) is a type error."""
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)
+
+
 def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs):
-    """jax.shard_map with a fallback for older jax (experimental module,
-    `check_rep` instead of `check_vma`).  The single home for this
-    version-dependent compat logic — use it instead of re-wrapping."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """`jax.shard_map` without varying-manual-axes checking (the sharded
+    atomics tier runs collectives on values the checker cannot type)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def logical_to_physical(logical: Sequence[Optional[str]],

@@ -85,6 +85,29 @@ def test_a001_quiet_on_provably_unique_and_vouched_indices():
                           _sds((4,), jnp.float32)) == []
 
 
+@pytest.mark.parametrize("where,machinery", [
+    ("jax", True), ("stdlib", True), ("purelib", False),
+    ("stdlib_site", False), ("repo", False)])
+def test_frame_filter_keeps_installed_packages(where, machinery):
+    """Frames in JAX and the standard library are dropped; an installed
+    (non-editable) repro under the stdlib prefix stays user code, so the
+    engine's own scatters stay sanctioned."""
+    import os
+    import sysconfig
+    from repro.analysis import rules
+    paths = sysconfig.get_paths()
+    file_name = {
+        "jax": jax.__file__,
+        "stdlib": os.path.join(paths["stdlib"], "functools.py"),
+        "purelib": os.path.join(paths["purelib"], "repro", "core",
+                                "rmw.py"),
+        "stdlib_site": os.path.join(paths["stdlib"], "site-packages",
+                                    "repro", "core", "rmw.py"),
+        "repo": rules.__file__,
+    }[where]
+    assert rules._is_machinery(file_name) is machinery
+
+
 def test_a001_quiet_on_single_update_and_sanctioned_execute():
     def single(buf, i, v):
         return buf.at[i].set(v)
@@ -290,9 +313,9 @@ def test_a005_fires_on_sharded_execute_outside_shard_map():
 def _shard_mapped(body):
     from jax.sharding import PartitionSpec as P
 
-    from repro.sharding import shard_map_compat
+    from repro.sharding import make_mesh, shard_map_compat
 
-    mesh = jax.make_mesh((1,), ("dev",))
+    mesh = make_mesh((1,), ("dev",))
     spec = P("dev")
     return shard_map_compat(body, mesh, (spec, spec, spec), (spec,))
 

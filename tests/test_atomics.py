@@ -29,6 +29,7 @@ import pytest
 
 from repro import atomics
 from repro.core.rmw import rmw_serialized
+from repro.sharding import make_mesh
 
 RNG = np.random.default_rng(5)
 
@@ -129,7 +130,7 @@ def _one_dev_shard_map(fn, mesh, n_in, n_out):
 
 
 def test_execute_sharded_detection_and_parity_one_device():
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = make_mesh((1,), ("x",))
     table, idx, vals = _batch()
     ref = rmw_serialized(table, idx, vals, "faa")
 
@@ -166,7 +167,7 @@ def test_local_table_rejects_sharded_tier_arguments():
 def test_sharded_perop_cas_rejects_non_oracle_backend():
     """Sharded per-op CAS mirrors the local tier: an explicit non-oracle
     backend override raises instead of being silently ignored."""
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = make_mesh((1,), ("x",))
     m, n = 8, 16
     table = jnp.zeros((m,), jnp.int32)
     idx = jnp.zeros((n,), jnp.int32)
@@ -310,7 +311,7 @@ def test_contention_hint_shifts_exchange_crossover():
 def test_contention_hint_never_changes_results():
     """The hint reaches only the selector: execution with an absurd hint is
     still bit-identical (1-device mesh exercises the full dispatch path)."""
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = make_mesh((1,), ("x",))
     table, idx, vals = _batch()
     ref = rmw_serialized(table, idx, vals, "faa")
 
@@ -341,7 +342,8 @@ from repro.core.rmw import rmw_serialized
 from repro.sharding import DEFAULT_RULES, shard_map_compat, use_mesh
 
 rng = np.random.default_rng(13)
-mesh = jax.make_mesh((2, 4), ("pod", "dev"))
+from repro.sharding import make_mesh
+mesh = make_mesh((2, 4), ("pod", "dev"))
 NDEV = 8
 SPEC = P(("pod", "dev"))
 out = {}
@@ -410,7 +412,7 @@ out["perop_cas/table_only"] = bool(np.array_equal(
     np.asarray(tabs).reshape(-1), np.asarray(ref.table)))
 
 # make_table wires the "rmw_table" logical rule to the model axis
-mesh2 = jax.make_mesh((2, 4), ("pod", "model"))
+mesh2 = make_mesh((2, 4), ("pod", "model"))
 with use_mesh(mesh2, dict(DEFAULT_RULES)):
     tbl = atomics.make_table(4096, jnp.int32)
 out["make_table/axis_is_model"] = tbl.axis == "model"

@@ -330,7 +330,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import atomics
 
-mesh = jax.make_mesh((2, 4), ("pod", "dev"))
+from repro.sharding import make_mesh
+mesh = make_mesh((2, 4), ("pod", "dev"))
 m = 256
 n = 512
 rng = np.random.default_rng(11)

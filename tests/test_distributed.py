@@ -27,7 +27,8 @@ from repro.data.pipeline import DataConfig, batch_kwargs_for, synthetic_batch
 from repro.sharding import use_mesh
 
 out = {}
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.sharding import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 for arch in ["gemma_2b", "deepseek_v3_671b", "jamba_1_5_large_398b"]:
     cfg = get_reduced(arch)
     rules = sh.arch_rules(cfg, mesh, "train")

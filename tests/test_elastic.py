@@ -30,7 +30,8 @@ model = build_model(cfg, attn_impl="ref", remat_policy="none", loss_chunk=64)
 opt_cfg = AdamWConfig()
 
 # write under an 8-chip mesh (data=4, model=2)
-mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+from repro.sharding import make_mesh
+mesh_a = make_mesh((4, 2), ("data", "model"))
 rules_a = sh.arch_rules(cfg, mesh_a, "train")
 with use_mesh(mesh_a, rules_a):
     params = model.init(jax.random.PRNGKey(0))

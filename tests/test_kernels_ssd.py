@@ -63,6 +63,21 @@ def test_grad_through_jnp_path():
     assert np.isfinite(np.asarray(g)).all()
 
 
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_grad_finite_when_chunk_decay_overflows(use_kernel):
+    """mamba2's init (A = -1, dt = softplus(0) ~ 0.69) over a 256-step
+    chunk: exp(l_t - l_s) above the diagonal overflows float32, and the
+    masked-out entries must not turn the gradient into NaN."""
+    b, s, h, p, n = 1, 256, 2, 8, 8
+    x, _, _, B, C = _inputs(b, s, h, p, n)
+    dt = jnp.full((b, s, h), float(jax.nn.softplus(0.0)), jnp.float32)
+    A = -jnp.ones((h,), jnp.float32)
+    g = jax.grad(lambda *a: ssd(*a, chunk=256, use_kernel=use_kernel).sum(),
+                 argnums=(0, 1, 2, 3, 4))(x, dt, A, B, C)
+    for gi in g:
+        assert np.isfinite(np.asarray(gi)).all()
+
+
 def test_dt_zero_is_identity_step():
     """dt=0 => exp(0)*h + 0: state unchanged (padding correctness)."""
     x, dt, A, B, C = _inputs(1, 16, 2, 8, 4)
@@ -70,3 +85,25 @@ def test_dt_zero_is_identity_step():
     h1, y = ssd_decode_step(h0, x[:, 0], jnp.zeros_like(dt[:, 0]), A,
                             B[:, 0], C[:, 0])
     np.testing.assert_allclose(h1, h0, rtol=1e-6)
+
+
+@pytest.mark.parametrize("return_final_state", [False, True])
+def test_kernel_path_grad_matches_jnp(return_final_state):
+    """The kernel path (interpreter here) differentiates through
+    `ssd_chunked_jnp`'s backward pass: same gradients, no pallas_call vjp."""
+    args = _inputs(1, 40, 2, 8, 4)
+
+    def loss(use_kernel):
+        def f(x, dt, A, B, C):
+            out = ssd(x, dt, A, B, C, chunk=16, use_kernel=use_kernel,
+                      return_final_state=return_final_state)
+            if return_final_state:
+                y, h = out
+                return (y ** 2).sum() + (h ** 2).sum()
+            return (out ** 2).sum()
+        return f
+
+    got = jax.grad(loss(True), argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(loss(False), argnums=(0, 1, 2, 3, 4))(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)

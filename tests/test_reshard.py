@@ -30,6 +30,7 @@ import pytest
 
 from repro import atomics
 from repro.atomics.layout import TableLayout, local_row, owner_shard
+from repro.sharding import make_mesh
 
 SCRIPT = r"""
 import os
@@ -189,7 +190,8 @@ for op_name in ("faa", "swp", "min", "max", "cas"):
 # same-fleet layout change rides the in-collective exchange path
 # ---------------------------------------------------------------------------
 
-mesh24 = jax.make_mesh((2, 4), ("pod", "dev"))
+from repro.sharding import make_mesh
+mesh24 = make_mesh((2, 4), ("pod", "dev"))
 tab0 = jnp.asarray(rng.integers(-1, 2, M), jnp.int32)
 tblC = atomics.AtomicTable(
     jax.device_put(tab0, NamedSharding(mesh24, P(("pod", "dev")))),
@@ -231,8 +233,8 @@ out["exchange/agrees_with_device_put"] = bool(
 # checkpointed tables restore under a different mesh (layout metadata)
 # ---------------------------------------------------------------------------
 
-mesh_a = jax.make_mesh((2, 4), ("pod", "model"))
-mesh_b = jax.make_mesh((4, 2), ("pod", "model"))
+mesh_a = make_mesh((2, 4), ("pod", "model"))
+mesh_b = make_mesh((4, 2), ("pod", "model"))
 from repro.sharding import DEFAULT_RULES
 with use_mesh(mesh_a, dict(DEFAULT_RULES)):
     tbl = atomics.make_table(M, jnp.int32, fill=0)
@@ -367,7 +369,7 @@ def test_layout_serialization_roundtrip_and_errors():
         TableLayout(num_slots=13, dtype="int32", axis=("dev",),
                     mesh_axes=(("dev", 4),)).m_local
     with pytest.raises(ValueError, match="not on mesh"):
-        TableLayout.from_mesh(jax.make_mesh((1,), ("x",)), num_slots=8,
+        TableLayout.from_mesh(make_mesh((1,), ("x",)), num_slots=8,
                               dtype=jnp.int32, axis="nope")
 
 

@@ -20,6 +20,7 @@ import pytest
 from repro import atomics
 from repro.atomics import (Cas, ExponentialBackoff, Faa, ImmediateRetry,
                            RetryPolicy, ShrinkBatch, execute_until)
+from repro.sharding import make_mesh
 
 
 def _contended_make_ops(n, slot=0):
@@ -174,7 +175,7 @@ def test_sharded_single_device_parity():
     n = 8
     local = execute_until(atomics.AtomicTable(jnp.zeros((8,), jnp.int32)),
                           _contended_make_ops(n), max_rounds=n)
-    mesh = jax.make_mesh((1,), ("dev",))
+    mesh = make_mesh((1,), ("dev",))
     data = jax.device_put(
         jnp.zeros((8,), jnp.int32),
         jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("dev")))
@@ -192,7 +193,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro import atomics
 from repro.atomics import Cas, execute_until
 
-mesh = jax.make_mesh((2, 4), ("pod", "dev"))
+from repro.sharding import make_mesh
+mesh = make_mesh((2, 4), ("pod", "dev"))
 P = jax.sharding.PartitionSpec
 data = jax.device_put(jnp.zeros((32,), jnp.int32),
                       jax.sharding.NamedSharding(mesh, P(("pod", "dev"))))

@@ -139,3 +139,23 @@ def test_ilp_gap_measured():
     t_comb = best_of(f_comb) / n
     threshold = 3.0 if jax.default_backend() == "tpu" else 0.3
     assert t_ser / t_comb > threshold, (t_ser, t_comb)
+
+
+@pytest.mark.parametrize("combine", [jnp.add, jnp.maximum, jnp.minimum])
+def test_blocked_segmented_scan_matches_one_scan(combine):
+    """Past SCAN_BLOCK elements the scan runs block by block, with segments
+    that cross block edges, an unflagged element 0 and a ragged tail; the
+    result is a per-segment accumulate's."""
+    from repro.core import rmw as rmw_mod
+    n = 2 * rmw_mod.SCAN_BLOCK + 77
+    r = np.random.default_rng(5)
+    v = r.integers(-50, 51, n).astype(np.int32)
+    f = r.random(n) < 1e-3
+    f[0] = False
+    got = rmw_mod.segmented_scan(jnp.asarray(v), jnp.asarray(f), combine)
+    ufunc = {jnp.add: np.add, jnp.maximum: np.maximum,
+             jnp.minimum: np.minimum}[combine]
+    starts = np.flatnonzero(np.concatenate([[True], f[1:]]))
+    want = np.concatenate([ufunc.accumulate(seg)
+                           for seg in np.split(v, starts[1:])])
+    np.testing.assert_array_equal(np.asarray(got), want)

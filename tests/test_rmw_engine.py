@@ -20,7 +20,7 @@ from repro.atomics import arrival_rank
 from repro.core import perf_model
 from repro.core.rmw import rmw_serialized
 from repro.core.rmw_engine import (BACKENDS, execute_backend, rmw_onehot,
-                                   select_backend)
+                                   select_backend, select_backend_with_cost)
 from repro.kernels.rmw.ops import rmw_apply_fetched
 from repro.kernels.rmw.ref import rmw_table_fetched_ref
 
@@ -225,6 +225,49 @@ def test_selector_respects_semantics():
     assert select_backend("swp", 4096, 256, dtype=jnp.int32) != "pallas"
 
 
+def test_pallas_fetched_path_refused_beyond_its_compile_limit():
+    """The fetched kernel compiles one program per table tile: past
+    PALLAS_FETCHED_MAX_SLOTS neither auto nor an explicit request runs it,
+    while the one-call table-only path still does."""
+    from repro.core.rmw_engine import PALLAS_FETCHED_MAX_SLOTS
+    big = PALLAS_FETCHED_MAX_SLOTS * 2
+    pallas = BACKENDS["pallas"]
+    assert pallas.supports("faa", dtype=jnp.float32,
+                           m=PALLAS_FETCHED_MAX_SLOTS)
+    assert not pallas.supports("faa", dtype=jnp.float32, m=big)
+    assert not pallas.supports("cas", dtype=jnp.float32, m=big,
+                               need_fetched=False)
+    assert pallas.supports("faa", dtype=jnp.float32, m=big,
+                           need_fetched=False)
+    spec = perf_model.cpu_default_spec()
+    sel = select_backend_with_cost("faa", 1024, big, spec,
+                                   dtype=jnp.float32)
+    assert "pallas" not in sel.costs
+    with pytest.raises(ValueError, match="pallas"):
+        execute_backend(jnp.zeros((big,), jnp.float32),
+                        jnp.zeros((8,), jnp.int32),
+                        jnp.ones((8,), jnp.float32), "faa",
+                        backend="pallas")
+
+
+def test_calibrated_spec_raises_for_unknown_tpu_kind(monkeypatch):
+    """A TPU whose device_kind has no spec row is an error, not v5e."""
+    from types import SimpleNamespace
+
+    from repro.core import rmw_engine
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        jax, "devices",
+        lambda *a: [SimpleNamespace(platform="tpu", device_kind="TPU v99")])
+    rmw_engine._reset_spec_cache()
+    try:
+        with pytest.raises(ValueError, match="TPU v99"):
+            rmw_engine.calibrated_spec()
+        assert perf_model.tpu_spec("TPU v5 lite") is perf_model.TPU_V5E
+    finally:
+        rmw_engine._reset_spec_cache()
+
+
 def test_selector_tracks_spec_costs():
     spec = perf_model.cpu_default_spec()
     name = select_backend("faa", 8192, 1024, spec)
@@ -249,6 +292,20 @@ def test_execute_validates():
     with pytest.raises(ValueError):
         execute_backend(t, i, i, "cas", jnp.zeros((2,), jnp.int32),
                     backend="onehot")
+
+
+def test_serialized_backend_takes_uniform_expected():
+    """A scalar CAS `expected` on the serialized backend means the same
+    expected for every op (it used to fail inside the oracle's scan)."""
+    table = jnp.asarray([-1, 3, -1, -1], jnp.int32)
+    idx = jnp.asarray([0, 0, 1, 2, 2], jnp.int32)
+    vals = jnp.asarray([5, 6, 7, -1, 8], jnp.int32)
+    want = rmw_serialized(table, idx, vals, "cas",
+                          jnp.full((5,), -1, jnp.int32))
+    for backend in ("serialized", "onehot", "sort"):
+        got = execute_backend(table, idx, vals, "cas", jnp.int32(-1),
+                              backend=backend)
+        _assert_same(want, got, backend)
 
 
 def test_execute_backend_modes_match_oracle():

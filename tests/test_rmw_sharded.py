@@ -31,17 +31,14 @@ from repro.core.rmw import rmw_serialized
 from repro.core.bfs import bfs, bfs_sharded, kronecker_graph
 
 rng = np.random.default_rng(7)
-mesh = jax.make_mesh((2, 4), ("pod", "dev"))
+from repro.sharding import make_mesh
+mesh = make_mesh((2, 4), ("pod", "dev"))
 NDEV = 8
 SPEC = P(("pod", "dev"))
 
 def shard_map(fn, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 out = {}
 

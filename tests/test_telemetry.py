@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from repro import atomics, telemetry
+from repro.sharding import make_mesh
 from repro.telemetry import drift
 
 
@@ -236,7 +237,7 @@ def test_retry_rounds_and_done_histogram():
 
 
 def test_reshard_migrate_event(monkeypatch):
-    mesh = jax.make_mesh((1,), ("dev",))
+    mesh = make_mesh((1,), ("dev",))
     data = jax.device_put(
         jnp.zeros((16,), jnp.int32),
         jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("dev")))
@@ -501,7 +502,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import atomics, telemetry
 from repro.sharding import shard_map_compat
 
-mesh = jax.make_mesh((2, 4), ("pod", "dev"))
+from repro.sharding import make_mesh
+mesh = make_mesh((2, 4), ("pod", "dev"))
 m_local, n_per = 8, 4
 idx = jnp.arange(8 * n_per, dtype=jnp.int32).reshape(8, n_per) % (8 * m_local)
 vals = jnp.ones((8, n_per), jnp.int32)
