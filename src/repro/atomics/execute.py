@@ -119,7 +119,7 @@ def _local_exec_stats(table: Array, indices: Array, values: Array, expected,
 def _dispatch_one(table: AtomicTable, op: AtomicOp, *, need_fetched: bool,
                   backend: str, strategy: str, spec,
                   distinct_slots: Optional[int], reverse_ranks: bool,
-                  collect_stats: bool = False):
+                  collect_stats: bool = False, span=None):
     if not isinstance(op, AtomicOp):
         raise TypeError(
             f"ops must be atomics.Faa/Swp/Min/Max/Cas instances, "
@@ -163,18 +163,17 @@ def _dispatch_one(table: AtomicTable, op: AtomicOp, *, need_fetched: bool,
                 f"sharded tier only, but the table is local — wrap it as "
                 f"AtomicTable(data, axis=...) (and call inside shard_map) "
                 f"or drop the sharded-tier arguments")
+        backend = rmw_engine.resolve_backend(
+            table.data, op.indices, op.kind, op.expected, backend=backend,
+            spec=spec, need_fetched=need_fetched)
+        if span is not None:
+            span.set(backend=backend)
         if collect_stats:
-            resolved = backend
-            if resolved == "auto":
-                resolved = rmw_engine.select_backend(
-                    op.kind, int(op.indices.shape[0]),
-                    int(table.data.shape[0]), spec,
-                    uniform_expected=(op.kind != "cas")
-                    or rmw_engine._is_uniform_expected(op.expected),
-                    dtype=table.dtype, need_fetched=need_fetched)
-            res, stats = _local_exec_stats(
-                table.data, op.indices, op.values, op.expected,
-                op=op.kind, backend=resolved, need_fetched=need_fetched)
+            with rmw_engine.host_span("atomics.dispatch", span is not None,
+                                      backend=backend):
+                res, stats = _local_exec_stats(
+                    table.data, op.indices, op.values, op.expected,
+                    op=op.kind, backend=backend, need_fetched=need_fetched)
         else:
             res = rmw_engine.execute_backend(
                 table.data, op.indices, op.values, op.kind, op.expected,
@@ -265,6 +264,38 @@ _DECISION_CACHE: dict = {}
 _DECISION_CACHE_MAX = 1024
 
 
+def _event_fields(table: AtomicTable, op: AtomicOp, traced: bool, *,
+                  need_fetched: bool, backend: str, strategy: str, spec,
+                  distinct_slots: Optional[int]) -> dict:
+    """A fresh copy of the call's decision fields, ``traced`` stamped."""
+    if table.is_sharded:
+        # trace-time only (axis sizes are trace-scoped): never cached, and
+        # the one-per-compilation cost is invisible
+        fields = _decision_fields(table, op, need_fetched=need_fetched,
+                                  backend=backend, strategy=strategy,
+                                  spec=spec, distinct_slots=distinct_slots)
+    else:
+        # NB the raw dtype object in the key: hashable, where str(dtype)
+        # costs ~10us/call
+        data = table.data
+        perop = op.kind == "cas" and op.expected is not None \
+            and jnp.ndim(op.expected) != 0
+        key = (op.kind, op.indices.shape[0], data.shape[0], backend,
+               strategy, need_fetched, perop, id(spec), distinct_slots,
+               data.dtype, rmw_engine._SPEC_EPOCH)
+        cached = _DECISION_CACHE.get(key)
+        if cached is None:
+            cached = _decision_fields(
+                table, op, need_fetched=need_fetched, backend=backend,
+                strategy=strategy, spec=spec, distinct_slots=distinct_slots)
+            if len(_DECISION_CACHE) >= _DECISION_CACHE_MAX:
+                _DECISION_CACHE.clear()
+            _DECISION_CACHE[key] = cached
+        fields = dict(cached)        # the cached template stays pristine
+    fields["traced"] = traced
+    return fields
+
+
 def _execute_one(table: AtomicTable, op: AtomicOp, *, need_fetched: bool,
                  backend: str, strategy: str, spec,
                  distinct_slots: Optional[int], reverse_ranks: bool,
@@ -288,96 +319,45 @@ def _execute_one(table: AtomicTable, op: AtomicOp, *, need_fetched: bool,
             distinct_slots=distinct_slots, reverse_ranks=reverse_ranks,
             axes_bound=(not table.is_sharded)
             or _axes_bound(_axis_names(table)))
-    if not telemetry.enabled():
-        return _dispatch_one(table, op, need_fetched=need_fetched,
-                             backend=backend, strategy=strategy, spec=spec,
-                             distinct_slots=distinct_slots,
-                             reverse_ranks=reverse_ranks,
-                             collect_stats=collect_stats)
+    kw = dict(need_fetched=need_fetched, backend=backend, strategy=strategy,
+              spec=spec, distinct_slots=distinct_slots,
+              reverse_ranks=reverse_ranks, collect_stats=collect_stats)
     if not isinstance(op, AtomicOp) or \
             (table.is_sharded and not _axes_bound(_axis_names(table))):
         # let the dispatcher raise its guidance errors un-instrumented
-        return _dispatch_one(table, op, need_fetched=need_fetched,
-                             backend=backend, strategy=strategy, spec=spec,
-                             distinct_slots=distinct_slots,
-                             reverse_ranks=reverse_ranks,
-                             collect_stats=collect_stats)
+        return _dispatch_one(table, op, **kw)
     data = table.data
-    if table.is_sharded:
-        # trace-time only (axis sizes are trace-scoped): never cached, and
-        # the one-per-compilation cost is invisible
-        fields = _decision_fields(table, op, need_fetched=need_fetched,
-                                  backend=backend, strategy=strategy,
-                                  spec=spec, distinct_slots=distinct_slots)
-        fields["event"] = "atomics.execute"
-    else:
-        # inlined cache lookup — on the eager hot path the function-call
-        # and kwargs overhead of a helper is itself a measurable slice of
-        # the <5% instrumentation budget.  NB the raw dtype object in the
-        # key: hashable, where str(dtype) costs ~10us/call.
-        perop = op.kind == "cas" and op.expected is not None \
-            and jnp.ndim(op.expected) != 0
-        key = (op.kind, op.indices.shape[0], data.shape[0], backend,
-               strategy, need_fetched, perop, id(spec), distinct_slots,
-               data.dtype, rmw_engine._SPEC_EPOCH)
-        fields = _DECISION_CACHE.get(key)
-        if fields is None:
-            fields = _decision_fields(
-                table, op, need_fetched=need_fetched, backend=backend,
-                strategy=strategy, spec=spec, distinct_slots=distinct_slots)
-            fields["event"] = "atomics.execute"   # pre-stamped template
-            if len(_DECISION_CACHE) >= _DECISION_CACHE_MAX:
-                _DECISION_CACHE.clear()
-            _DECISION_CACHE[key] = fields
-        fields = dict(fields)        # the cached template stays pristine
-    traced = isinstance(data, jax.core.Tracer) \
-        or isinstance(op.indices, jax.core.Tracer)
-    # _tcore flag reads instead of the telemetry.*_enabled() accessors:
-    # each saved call is ~0.15us against the overhead budget
-    if traced or not _tcore._sync:
-        if _tcore._annotate and not traced:
-            with telemetry.annotation(
-                    f"atomics.execute/{fields.get('tier')}"):
-                out = _dispatch_one(table, op, need_fetched=need_fetched,
-                                    backend=backend, strategy=strategy,
-                                    spec=spec, distinct_slots=distinct_slots,
-                                    reverse_ranks=reverse_ranks,
-                                    collect_stats=collect_stats)
-        else:
-            out = _dispatch_one(table, op, need_fetched=need_fetched,
-                                backend=backend, strategy=strategy,
-                                spec=spec, distinct_slots=distinct_slots,
-                                reverse_ranks=reverse_ranks,
-                                collect_stats=collect_stats)
-    else:
+    traced = not rmw_engine._eager(data, op.indices)
+    fields = _event_fields(table, op, traced, need_fetched=need_fetched,
+                           backend=backend, strategy=strategy, spec=spec,
+                           distinct_slots=distinct_slots) \
+        if telemetry.enabled() else None
+    if traced:
+        # trace time: one decision event per compilation, no span
+        out = _dispatch_one(table, op, **kw)
+        if fields is not None:
+            fields["event"] = "atomics.execute"
+            telemetry.record_event(fields)
+        return out
+    if fields is None:
+        fields = {"n": int(op.indices.shape[0]), "op": op.kind}
+    # the eager call: one span, which is also the decision event when the
+    # stream is on (its fields, plus wall_s and measured_s under sync)
+    with telemetry.span("atomics.execute", **fields) as sp:
         t0 = time.perf_counter()
-        if _tcore._annotate:
-            with telemetry.annotation(
-                    f"atomics.execute/{fields.get('tier')}"):
-                out = _dispatch_one(table, op, need_fetched=need_fetched,
-                                    backend=backend, strategy=strategy,
-                                    spec=spec, distinct_slots=distinct_slots,
-                                    reverse_ranks=reverse_ranks,
-                                    collect_stats=collect_stats)
-        else:
-            out = _dispatch_one(table, op, need_fetched=need_fetched,
-                                backend=backend, strategy=strategy,
-                                spec=spec, distinct_slots=distinct_slots,
-                                reverse_ranks=reverse_ranks,
-                                collect_stats=collect_stats)
-        sync = (out[0].data, out[1], out[2])
-        if out[3] is not None:
-            sync += (out[3],)
-        jax.block_until_ready(sync)
-        fields["measured_s"] = time.perf_counter() - t0
-    # the cache-copy dict becomes the event itself (record_event skips the
-    # kwargs rebuild that `record` pays — this is the hottest record site)
-    fields["traced"] = traced
-    telemetry.record_event(fields)
-    if out[3] is not None and not traced and _tcore._sync:
+        out = _dispatch_one(table, op, span=sp, **kw)
+        # _tcore flag read instead of telemetry.sync_enabled(): each saved
+        # call is ~0.15us against the overhead budget
+        if _tcore._enabled and _tcore._sync:
+            sync = (out[0].data, out[1], out[2])
+            if out[3] is not None:
+                sync += (out[3],)
+            jax.block_until_ready(sync)
+            sp.set(measured_s=time.perf_counter() - t0)
+    if out[3] is not None and _tcore._enabled and _tcore._sync:
         # PR-7 jit discipline: contention.* events only at sync boundaries —
-        # the eager sync branch above already blocked on the stats leaves,
-        # so the host readout below costs no extra device round trip.
+        # the sync above already blocked on the stats leaves, so the host
+        # readout below costs no extra device round trip.
         telemetry.record_event(_cstats.stats_to_fields(
             out[3], tier=fields.get("tier"), op=op.kind,
             n=fields.get("n"), m=fields.get("m"), traced=False))

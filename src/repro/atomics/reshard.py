@@ -371,22 +371,20 @@ def migrate(table: AtomicTable, dst_mesh, *, axis: object = "auto",
         dst = TableLayout(num_slots=src.num_slots, dtype=src.dtype)
     plan = plan_reshard(src, dst, dst_mesh=dst_mesh, src_mesh=src_mesh,
                         live=True, path=path, spec=spec)
-    if not telemetry.enabled():
-        return plan.execute(table)
-    with telemetry.annotation(f"atomics.reshard.migrate/{plan.path}"):
+    # one span: the profiler's range of the migration and, when the stream
+    # is on, its event (measured against a block on the new table)
+    with telemetry.span("atomics.reshard.migrate", path=plan.path) as sp:
         t0 = time.perf_counter()
         out = plan.execute(table)
-        jax.block_until_ready(out.data)
-        dt = time.perf_counter() - t0
-    telemetry.record(
-        "atomics.reshard.migrate", path=plan.path,
-        tier="migration", n_slots=src.num_slots,
-        src_shards=src.n_shards, dst_shards=dst.n_shards,
-        src_replicas=src.n_replicas, dst_replicas=dst.n_replicas,
-        predicted_s=plan.predicted_s.get(plan.path),
-        predicted_all={k: v for k, v in plan.predicted_s.items()
-                       if math.isfinite(v)},
-        measured_s=dt)
+        if telemetry.enabled():
+            jax.block_until_ready(out.data)
+            sp.set(tier="migration", n_slots=src.num_slots,
+                   src_shards=src.n_shards, dst_shards=dst.n_shards,
+                   src_replicas=src.n_replicas, dst_replicas=dst.n_replicas,
+                   predicted_s=plan.predicted_s.get(plan.path),
+                   predicted_all={k: v for k, v in plan.predicted_s.items()
+                                  if math.isfinite(v)},
+                   measured_s=time.perf_counter() - t0)
     return out
 
 

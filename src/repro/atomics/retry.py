@@ -303,7 +303,7 @@ def _exec_round_sharded(table: AtomicTable, kind: str, idx: np.ndarray,
             except Exception:  # noqa: BLE001 — never break the round
                 pass
     stats = None
-    with telemetry.annotation("atomics.retry.exchange"):
+    with telemetry.span("atomics.retry.exchange", n=k, n_exec=per):
         if collect_stats:
             tab, fetched, success, stats = fn(table.data, *args)
         else:

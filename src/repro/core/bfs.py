@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import atomics
+from repro import atomics, telemetry
 from repro.sharding import make_mesh, shard_map_compat
 
 Array = jax.Array
@@ -57,6 +57,47 @@ class BfsResult:
     edges_traversed: int
 
 
+def _claim(parent: Array, cand_dst: Array, cand_par: Array, n: int,
+           op: str, backend: str) -> Array:
+    """One level's parent claims: every candidate edge's parent update
+    through the chosen typed op; returns the new parent array."""
+    if op == "cas":
+        res = atomics.execute(
+            parent, atomics.Cas(cand_dst, cand_par, expected=-1),
+            backend=backend, need_fetched=False)
+        new_parent = res.table.data
+    elif op == "swp":
+        # swap unconditionally, then revert overwrites of visited nodes.
+        # The restore value is the FIRST collider's fetched (the original
+        # parent), so the revert stream runs reversed (last-wins of the
+        # reversed order == first in program order).
+        res = atomics.execute(parent, atomics.Swp(cand_dst, cand_par),
+                              backend=backend)
+        visited_before = res.fetched != -1
+        revert_idx = jnp.where(visited_before, cand_dst, n)
+        new_parent = atomics.execute(
+            res.table, atomics.Swp(revert_idx[::-1], res.fetched[::-1]),
+            backend=backend, need_fetched=False).table.data
+    else:  # faa with revert (the paper's "complex scheme")
+        delta = jnp.where(parent[jnp.clip(cand_dst, 0, n - 1)] == -1,
+                          cand_par + 1, 0)
+        res = atomics.execute(parent, atomics.Faa(cand_dst, delta),
+                              backend=backend, need_fetched=False)
+        over = res.table.data  # -1 + sum(deltas); keep 1st contributor
+        # revert: recompute exact winner via min-combine of parities
+        first = atomics.execute(
+            jnp.full((n,), jnp.iinfo(jnp.int32).max, jnp.int32),
+            atomics.Min(cand_dst,
+                        jnp.where(delta > 0, cand_par,
+                                  jnp.iinfo(jnp.int32).max)),
+            backend=backend, need_fetched=False).table.data
+        new_parent = jnp.where(
+            (parent == -1) & (first != jnp.iinfo(jnp.int32).max),
+            first, parent)
+        del over
+    return new_parent
+
+
 @partial(jax.jit, static_argnames=("n", "op", "max_levels", "backend"))
 def _bfs_run(src: Array, dst: Array, root, n: int, op: str,
              max_levels: int = 64, backend: str = "auto"):
@@ -64,45 +105,15 @@ def _bfs_run(src: Array, dst: Array, root, n: int, op: str,
 
     def level(state):
         parent, frontier, lvl, edges = state
-        active = frontier[src]                       # edge's src in frontier
-        cand_dst = jnp.where(active, dst, n)         # OOR -> dropped
-        cand_par = src.astype(jnp.int32)
-        if op == "cas":
-            res = atomics.execute(
-                parent, atomics.Cas(cand_dst, cand_par, expected=-1),
-                backend=backend, need_fetched=False)
-            new_parent = res.table.data
-        elif op == "swp":
-            # swap unconditionally, then revert overwrites of visited nodes.
-            # The restore value is the FIRST collider's fetched (the original
-            # parent), so the revert stream runs reversed (last-wins of the
-            # reversed order == first in program order).
-            res = atomics.execute(parent, atomics.Swp(cand_dst, cand_par),
-                                  backend=backend)
-            visited_before = res.fetched != -1
-            revert_idx = jnp.where(visited_before, cand_dst, n)
-            new_parent = atomics.execute(
-                res.table, atomics.Swp(revert_idx[::-1], res.fetched[::-1]),
-                backend=backend, need_fetched=False).table.data
-        else:  # faa with revert (the paper's "complex scheme")
-            delta = jnp.where(parent[jnp.clip(cand_dst, 0, n - 1)] == -1,
-                              cand_par + 1, 0)
-            res = atomics.execute(parent, atomics.Faa(cand_dst, delta),
-                                  backend=backend, need_fetched=False)
-            over = res.table.data  # -1 + sum(deltas); keep 1st contributor
-            # revert: recompute exact winner via min-combine of parities
-            first = atomics.execute(
-                jnp.full((n,), jnp.iinfo(jnp.int32).max, jnp.int32),
-                atomics.Min(cand_dst,
-                            jnp.where(delta > 0, cand_par,
-                                      jnp.iinfo(jnp.int32).max)),
-                backend=backend, need_fetched=False).table.data
-            new_parent = jnp.where(
-                (parent == -1) & (first != jnp.iinfo(jnp.int32).max),
-                first, parent)
-            del over
-        new_frontier = (new_parent != -1) & (parent == -1)
-        edges = edges + jnp.sum(active)
+        with jax.named_scope("bfs.expand"):
+            active = frontier[src]                   # edge's src in frontier
+            cand_dst = jnp.where(active, dst, n)     # OOR -> dropped
+            cand_par = src.astype(jnp.int32)
+        with jax.named_scope("bfs.claim"):
+            new_parent = _claim(parent, cand_dst, cand_par, n, op, backend)
+        with jax.named_scope("bfs.frontier"):
+            new_frontier = (new_parent != -1) & (parent == -1)
+            edges = edges + jnp.sum(active)
         return new_parent, new_frontier, lvl + 1, edges
 
     def cond(state):
@@ -118,12 +129,17 @@ def _bfs_run(src: Array, dst: Array, root, n: int, op: str,
 def bfs(src: np.ndarray, dst: np.ndarray, n: int, root: int = 0,
         op: str = "cas", backend: str = "auto") -> BfsResult:
     """Level-synchronous BFS; op ∈ {cas, swp, faa} picks the combiner and
-    ``backend`` the RMW engine implementation ("auto" = cost-model pick)."""
-    parent, lvl, edges = _bfs_run(
-        jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32),
-        jnp.int32(root), int(n), op, backend=backend)
-    return BfsResult(parent=parent, levels=int(lvl),
-                     edges_traversed=int(edges))
+    ``backend`` the RMW engine implementation ("auto" = cost-model pick).
+    The call is the host span ``bfs.traversal``, which carries the level
+    count and the edges traversed read back at its end."""
+    with telemetry.span("bfs.traversal", n=int(n), op=op) as sp:
+        parent, lvl, edges = _bfs_run(
+            jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32),
+            jnp.int32(root), int(n), op, backend=backend)
+        out = BfsResult(parent=parent, levels=int(lvl),
+                        edges_traversed=int(edges))
+        sp.set(levels=out.levels, edges_traversed=out.edges_traversed)
+    return out
 
 
 def bfs_sharded(src: np.ndarray, dst: np.ndarray, n: int, root: int = 0,

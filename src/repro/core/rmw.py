@@ -200,32 +200,43 @@ def rmw_combining(table: Array, indices: Array, values: Array, op: str,
         # Uniform-expected CAS is combinable; otherwise use the oracle.
         return _cas_uniform(table, indices, values, expected)
 
-    order, inv, idx_s, seg_start, (val_s,) = _sort_by_index(indices, values)
-    base = table[idx_s]
+    with jax.named_scope("rmw.sort"):
+        order, inv, idx_s, seg_start, (val_s,) = _sort_by_index(indices,
+                                                                values)
+    with jax.named_scope("rmw.gather"):
+        base = table[idx_s]
 
     if op == "swp":
-        prev = jnp.roll(val_s, 1, axis=0)
-        fetched_s = jnp.where(seg_start, base, prev)
-        # last-wins: route non-final writes to a scratch row
-        is_end = jnp.concatenate([seg_start[1:], jnp.ones((1,), bool)])
-        scratch = jnp.asarray(table.shape[0], idx_s.dtype)
-        write_idx = jnp.where(is_end, idx_s, scratch)
-        padded = jnp.concatenate([table, table[:1]], axis=0)
-        new_table = padded.at[write_idx].set(val_s)[:-1]
-        return RmwResult(new_table, fetched_s[inv], jnp.ones((n,), bool))
+        with jax.named_scope("rmw.scan"):
+            prev = jnp.roll(val_s, 1, axis=0)
+            fetched_s = jnp.where(seg_start, base, prev)
+        with jax.named_scope("rmw.scatter"):
+            # last-wins: route non-final writes to a scratch row
+            is_end = jnp.concatenate([seg_start[1:], jnp.ones((1,), bool)])
+            scratch = jnp.asarray(table.shape[0], idx_s.dtype)
+            write_idx = jnp.where(is_end, idx_s, scratch)
+            padded = jnp.concatenate([table, table[:1]], axis=0)
+            new_table = padded.at[write_idx].set(val_s)[:-1]
+        with jax.named_scope("rmw.unsort"):
+            fetched = fetched_s[inv]
+        return RmwResult(new_table, fetched, jnp.ones((n,), bool))
 
     comb = _combine_fn(op)
-    incl = segmented_scan(val_s, seg_start, comb)
-    exc = _exclusive_from_inclusive(incl, val_s, seg_start,
-                                    _identity(op, values.dtype))
-    fetched_s = comb(base, exc) if op != "faa" else base + exc
-    if op == "faa":
-        new_table = table.at[indices].add(values)
-    elif op == "min":
-        new_table = table.at[indices].min(values)
-    else:
-        new_table = table.at[indices].max(values)
-    return RmwResult(new_table, fetched_s[inv], jnp.ones((n,), bool))
+    with jax.named_scope("rmw.scan"):
+        incl = segmented_scan(val_s, seg_start, comb)
+        exc = _exclusive_from_inclusive(incl, val_s, seg_start,
+                                        _identity(op, values.dtype))
+        fetched_s = comb(base, exc) if op != "faa" else base + exc
+    with jax.named_scope("rmw.scatter"):
+        if op == "faa":
+            new_table = table.at[indices].add(values)
+        elif op == "min":
+            new_table = table.at[indices].min(values)
+        else:
+            new_table = table.at[indices].max(values)
+    with jax.named_scope("rmw.unsort"):
+        fetched = fetched_s[inv]
+    return RmwResult(new_table, fetched, jnp.ones((n,), bool))
 
 
 def _cas_uniform(table: Array, indices: Array, values: Array,
@@ -234,29 +245,37 @@ def _cas_uniform(table: Array, indices: Array, values: Array,
     wins; later colliders observe the winner's value and fail (paper's BFS
     pattern: cas(parent[v], -1, u)).  1-D tables only."""
     exp_all = jnp.broadcast_to(jnp.asarray(expected, table.dtype), values.shape)
-    order, inv, idx_s, seg_start, (val_s, exp_s) = _sort_by_index(
-        indices, values, exp_all)
-    base = table[idx_s]
-    matches = base == exp_s  # slot held `expected` before the batch
-    # Serialized chain semantics: ops succeed while the slot still holds
-    # `expected`.  Writing desired == expected keeps the chain alive; the
-    # first op writing desired != expected ("break op") ends it.
-    eq = (val_s == exp_s).astype(jnp.int32)
-    incl_alive = segmented_scan(eq, seg_start, jnp.minimum)
-    alive_excl = _exclusive_from_inclusive(incl_alive, eq, seg_start, 1
-                                           ).astype(bool)
-    success_s = matches & alive_excl
-    break_op = success_s & (eq == 0)
-    contrib = jnp.where(break_op, val_s, jnp.zeros_like(val_s))
-    incl_break = segmented_scan(contrib, seg_start, jnp.add)
-    break_excl = _exclusive_from_inclusive(incl_break, contrib, seg_start, 0)
-    fetched_s = jnp.where(alive_excl | ~matches, base, break_excl)
-    # Table write: only the break op changes the slot's value.
-    scratch = jnp.asarray(table.shape[0], idx_s.dtype)
-    write_idx = jnp.where(break_op, idx_s, scratch)
-    padded = jnp.concatenate([table, table[:1]], axis=0)
-    new_table = padded.at[write_idx].set(val_s)[:-1]
-    return RmwResult(new_table, fetched_s[inv], success_s[inv])
+    with jax.named_scope("rmw.sort"):
+        order, inv, idx_s, seg_start, (val_s, exp_s) = _sort_by_index(
+            indices, values, exp_all)
+    with jax.named_scope("rmw.gather"):
+        base = table[idx_s]
+    with jax.named_scope("rmw.scan"):
+        matches = base == exp_s  # slot held `expected` before the batch
+        # Serialized chain semantics: ops succeed while the slot still
+        # holds `expected`.  Writing desired == expected keeps the chain
+        # alive; the first op writing desired != expected ("break op")
+        # ends it.
+        eq = (val_s == exp_s).astype(jnp.int32)
+        incl_alive = segmented_scan(eq, seg_start, jnp.minimum)
+        alive_excl = _exclusive_from_inclusive(incl_alive, eq, seg_start, 1
+                                               ).astype(bool)
+        success_s = matches & alive_excl
+        break_op = success_s & (eq == 0)
+        contrib = jnp.where(break_op, val_s, jnp.zeros_like(val_s))
+        incl_break = segmented_scan(contrib, seg_start, jnp.add)
+        break_excl = _exclusive_from_inclusive(incl_break, contrib,
+                                               seg_start, 0)
+        fetched_s = jnp.where(alive_excl | ~matches, base, break_excl)
+    with jax.named_scope("rmw.scatter"):
+        # Table write: only the break op changes the slot's value.
+        scratch = jnp.asarray(table.shape[0], idx_s.dtype)
+        write_idx = jnp.where(break_op, idx_s, scratch)
+        padded = jnp.concatenate([table, table[:1]], axis=0)
+        new_table = padded.at[write_idx].set(val_s)[:-1]
+    with jax.named_scope("rmw.unsort"):
+        fetched, success = fetched_s[inv], success_s[inv]
+    return RmwResult(new_table, fetched, success)
 
 
 def scatter_add_grads(grad_table: Array, token_ids: Array,

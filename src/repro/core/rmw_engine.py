@@ -45,6 +45,7 @@ README "RMW engine").
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -53,6 +54,7 @@ from typing import Callable, Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.core import perf_model
 from repro.core.placement import PlacementState, Tier
 from repro.core.rmw import (OPS, RmwResult, _identity, rmw_combining,
@@ -136,7 +138,8 @@ def rmw_onehot(table: Array, indices: Array, values: Array, op: str,
             prefix = same.astype(vb.dtype) @ vb           # tri-masked matmul
             fetched = base + prefix
             ok = jnp.ones((b,), bool)
-            acc = acc.at[ib].add(vb)
+            with jax.named_scope("rmw.scatter"):
+                acc = acc.at[ib].add(vb)
         elif op in ("min", "max"):
             ident = _identity(op, vb.dtype)
             comb = jnp.minimum if op == "min" else jnp.maximum
@@ -145,7 +148,9 @@ def rmw_onehot(table: Array, indices: Array, values: Array, op: str,
                       else jnp.max(masked, axis=1))
             fetched = comb(base, prefix)
             ok = jnp.ones((b,), bool)
-            acc = acc.at[ib].min(vb) if op == "min" else acc.at[ib].max(vb)
+            with jax.named_scope("rmw.scatter"):
+                acc = acc.at[ib].min(vb) if op == "min" \
+                    else acc.at[ib].max(vb)
         elif op == "swp":
             mpos = jnp.where(same, pos[None, :], -1).max(axis=1)
             prev = vb[jnp.clip(mpos, 0)]
@@ -155,7 +160,8 @@ def rmw_onehot(table: Array, indices: Array, values: Array, op: str,
             later_same = (ib[:, None] == ib[None, :]) \
                 & (pos[:, None] < pos[None, :])
             is_last = ~later_same.any(axis=1)
-            acc = acc.at[jnp.where(is_last, ib, m)].set(vb)
+            with jax.named_scope("rmw.scatter"):
+                acc = acc.at[jnp.where(is_last, ib, m)].set(vb)
         else:  # cas, uniform expected
             # Serialized CAS chains compose associatively: the slot's value
             # after a collider group is `first value != expected` (writes of
@@ -169,7 +175,8 @@ def rmw_onehot(table: Array, indices: Array, values: Array, op: str,
             # block winner = first op with value != expected at a live slot
             is_first_ne = ne & (fpos == b)
             write = is_first_ne & (base == exp)
-            acc = acc.at[jnp.where(write, ib, m)].set(vb)
+            with jax.named_scope("rmw.scatter"):
+                acc = acc.at[jnp.where(write, ib, m)].set(vb)
         return acc, (fetched, ok)
 
     acc, (fetched, ok) = jax.lax.scan(
@@ -186,27 +193,28 @@ def _tables_only(table: Array, indices: Array, values: Array, op: str,
     remapped past the table so they drop too instead of wrapping
     NumPy-style — matching the fetched path on identical inputs.
     """
-    n = indices.shape[0]
-    m = table.shape[0]
-    idx = indices.astype(jnp.int32)
-    idx = jnp.where(idx < 0, jnp.int32(m), idx)
-    pos = jnp.arange(n, dtype=jnp.int32)
-    if op == "faa":
-        tab = table.at[idx].add(values)
-    elif op in ("min", "max"):
-        tab = (table.at[idx].min(values) if op == "min"
-               else table.at[idx].max(values))
-    elif op == "swp":
-        last = jnp.full((m,), -1, jnp.int32).at[idx].max(pos)
-        tab = jnp.where(last >= 0, values[jnp.clip(last, 0)], table)
-    else:  # cas, uniform expected: slot = first value != expected if live
-        e = jnp.asarray(expected, table.dtype)
-        first = jnp.full((m,), n, jnp.int32).at[idx].min(
-            jnp.where(values != e, pos, n))
-        tab = jnp.where((table == e) & (first < n),
-                        values[jnp.clip(first, 0, n - 1)], table)
-    return RmwResult(tab, jnp.zeros((n,), values.dtype),
-                     jnp.zeros((n,), bool))
+    with jax.named_scope("rmw.scatter"):
+        n = indices.shape[0]
+        m = table.shape[0]
+        idx = indices.astype(jnp.int32)
+        idx = jnp.where(idx < 0, jnp.int32(m), idx)
+        pos = jnp.arange(n, dtype=jnp.int32)
+        if op == "faa":
+            tab = table.at[idx].add(values)
+        elif op in ("min", "max"):
+            tab = (table.at[idx].min(values) if op == "min"
+                   else table.at[idx].max(values))
+        elif op == "swp":
+            last = jnp.full((m,), -1, jnp.int32).at[idx].max(pos)
+            tab = jnp.where(last >= 0, values[jnp.clip(last, 0)], table)
+        else:  # cas, uniform expected: slot = first value != expected if live
+            e = jnp.asarray(expected, table.dtype)
+            first = jnp.full((m,), n, jnp.int32).at[idx].min(
+                jnp.where(values != e, pos, n))
+            tab = jnp.where((table == e) & (first < n),
+                            values[jnp.clip(first, 0, n - 1)], table)
+        return RmwResult(tab, jnp.zeros((n,), values.dtype),
+                         jnp.zeros((n,), bool))
 
 
 def slot_occupancy(indices: Array, m: int) -> Array:
@@ -538,6 +546,36 @@ def select_backend(op: str, n: int, m: int,
         need_fetched=need_fetched).choice
 
 
+def _eager(*arrays) -> bool:
+    """True when none of ``arrays`` is being traced (an eager call)."""
+    return not any(isinstance(a, jax.core.Tracer) for a in arrays)
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def host_span(name: str, eager: bool, **fields):
+    """A `telemetry.span` on an eager call; nothing while jit traces."""
+    return telemetry.span(name, **fields) if eager else _NO_SPAN
+
+
+def resolve_backend(table: Array, indices: Array, op: str,
+                    expected: Optional[Array] = None, *,
+                    backend: str = "auto",
+                    spec: Optional[perf_model.HardwareSpec] = None,
+                    need_fetched: bool = True) -> str:
+    """The backend ``execute_backend`` runs: ``backend`` itself, or for
+    "auto" the cost model's pick, inside the host span ``atomics.select``
+    on an eager call (selection happens at trace time under jit)."""
+    if backend != "auto":
+        return backend
+    with host_span("atomics.select", _eager(table, indices)):
+        return select_backend(
+            op, int(indices.shape[0]), int(table.shape[0]), spec,
+            uniform_expected=(op != "cas") or _is_uniform_expected(expected),
+            dtype=table.dtype, need_fetched=need_fetched)
+
+
 def execute_backend(table: Array, indices: Array, values: Array, op: str,
                     expected: Optional[Array] = None, *,
                     backend: str = "auto",
@@ -551,7 +589,9 @@ def execute_backend(table: Array, indices: Array, values: Array, op: str,
 
     Shapes are static under jit, so auto-selection happens at trace time and
     costs nothing at runtime.  All backends return the serialized-equivalent
-    :class:`~repro.core.rmw.RmwResult`.
+    :class:`~repro.core.rmw.RmwResult`.  On an eager call the pick is the
+    host span ``atomics.select`` and the call into the jitted backend, up
+    to its return, the span ``atomics.dispatch``.
 
     ``need_fetched=False`` declares that the caller consumes only ``.table``
     (for CAS, also not ``.success``): backends may then skip the per-op
@@ -563,11 +603,8 @@ def execute_backend(table: Array, indices: Array, values: Array, op: str,
         raise ValueError(f"unknown op {op!r}")
     if op == "cas" and expected is None:
         raise ValueError("cas requires `expected`")
-    if backend == "auto":
-        backend = select_backend(
-            op, int(indices.shape[0]), int(table.shape[0]), spec,
-            uniform_expected=(op != "cas") or _is_uniform_expected(expected),
-            dtype=table.dtype, need_fetched=need_fetched)
+    backend = resolve_backend(table, indices, op, expected, backend=backend,
+                              spec=spec, need_fetched=need_fetched)
     try:
         b = BACKENDS[backend]
     except KeyError:
@@ -583,5 +620,7 @@ def execute_backend(table: Array, indices: Array, values: Array, op: str,
         raise ValueError(
             f"backend {b.name!r} supports CAS only with a scalar (uniform) "
             f"`expected`; per-op expected arrays need the serialized oracle")
-    return b.run(table, indices, values, op, expected,
-                 need_fetched=need_fetched)
+    with host_span("atomics.dispatch", _eager(table, indices),
+                    backend=backend):
+        return b.run(table, indices, values, op, expected,
+                     need_fetched=need_fetched)

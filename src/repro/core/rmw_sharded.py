@@ -232,21 +232,25 @@ def _push(gidx: Array, vals: Array, op: str, expected, *, axis: AxisNames,
     rank on `axis` (same for every op of a group).  Returns the stage record
     and the received flat batch (source-rank-major — the arrival order;
     descending source rank when ``reverse``)."""
-    st = _combine(gidx, vals, op, expected, need_fetched=need_fetched,
-                  backend=backend, spec=spec)
-    dest_s = dest[st.order]
-    valid = st.sidx < m_global
-    is_rep = st.seg_start & valid
-    scratch = n_dest * cap
-    slotpos = _rank_slotpos(dest_s, is_rep, n_dest, cap)
-    send_idx = _scatter_padded(m_global, jnp.int32, slotpos,
-                               jnp.where(is_rep, st.sidx, m_global), scratch)
-    send_val = _scatter_padded(0, vals.dtype, slotpos,
-                               st.combined[st.seg_id], scratch)
-    recv_idx, recv_val = _route_pair(send_idx, send_val, axis, n_dest, cap)
-    if reverse:
-        recv_idx = _flip_lanes(recv_idx, n_dest, cap)
-        recv_val = _flip_lanes(recv_val, n_dest, cap)
+    with jax.named_scope("exchange.precombine"):
+        st = _combine(gidx, vals, op, expected, need_fetched=need_fetched,
+                      backend=backend, spec=spec)
+        dest_s = dest[st.order]
+        valid = st.sidx < m_global
+        is_rep = st.seg_start & valid
+        scratch = n_dest * cap
+        slotpos = _rank_slotpos(dest_s, is_rep, n_dest, cap)
+        send_idx = _scatter_padded(m_global, jnp.int32, slotpos,
+                                   jnp.where(is_rep, st.sidx, m_global),
+                                   scratch)
+        send_val = _scatter_padded(0, vals.dtype, slotpos,
+                                   st.combined[st.seg_id], scratch)
+    with jax.named_scope("exchange.send"):
+        recv_idx, recv_val = _route_pair(send_idx, send_val, axis, n_dest,
+                                         cap)
+        if reverse:
+            recv_idx = _flip_lanes(recv_idx, n_dest, cap)
+            recv_val = _flip_lanes(recv_val, n_dest, cap)
     stage = _Stage(axis=axis, n_dest=n_dest, cap=cap, comb=st,
                    slotpos=slotpos, m_global=m_global, reverse=reverse)
     return stage, recv_idx, recv_val
@@ -256,37 +260,38 @@ def _pop(stage: _Stage, bases_recv: Array, op: str, expected
          ) -> Tuple[Array, Array]:
     """Return one level: route the resolver's bases back to the sources and
     reconstruct exact per-op fetched/success from (base, local chain)."""
-    st = stage.comb
-    n = st.sidx.shape[0]
-    if stage.reverse:       # undo the receive-side flip before routing back
-        bases_recv = _flip_lanes(bases_recv, stage.n_dest, stage.cap)
-    ret = jax.lax.all_to_all(bases_recv.reshape(stage.n_dest, stage.cap),
-                             stage.axis, split_axis=0,
-                             concat_axis=0).reshape(-1)
-    ret = jnp.concatenate([ret, jnp.zeros((1,), ret.dtype)])
-    base_rep = ret[stage.slotpos]                     # scratch -> 0
-    base_seg = jnp.zeros((n + 1,), ret.dtype).at[
-        jnp.where(st.seg_start, st.seg_id, n)].set(base_rep)
-    base = base_seg[st.seg_id]                        # per sorted op
-    if op == "faa":
-        fetched = base + st.loc_fetched
-        success = jnp.ones((n,), bool)
-    elif op in ("min", "max"):
-        comb = jnp.minimum if op == "min" else jnp.maximum
-        fetched = comb(base, st.loc_fetched)
-        success = jnp.ones((n,), bool)
-    elif op == "swp":
-        fetched = jnp.where(st.seg_start, base, st.loc_fetched)
-        success = jnp.ones((n,), bool)
-    else:  # cas (uniform): the local chain assumed base == expected
-        exp = jnp.asarray(expected, base.dtype)
-        live = base == exp
-        fetched = jnp.where(live, st.loc_fetched, base)
-        success = live & st.loc_success
-    valid = st.sidx < stage.m_global
-    fetched = jnp.where(valid, fetched, jnp.zeros((), fetched.dtype))
-    success = success & valid
-    return fetched[st.inv], success[st.inv]
+    with jax.named_scope("exchange.return"):
+        st = stage.comb
+        n = st.sidx.shape[0]
+        if stage.reverse:   # undo the receive-side flip before routing back
+            bases_recv = _flip_lanes(bases_recv, stage.n_dest, stage.cap)
+        ret = jax.lax.all_to_all(bases_recv.reshape(stage.n_dest, stage.cap),
+                                 stage.axis, split_axis=0,
+                                 concat_axis=0).reshape(-1)
+        ret = jnp.concatenate([ret, jnp.zeros((1,), ret.dtype)])
+        base_rep = ret[stage.slotpos]                     # scratch -> 0
+        base_seg = jnp.zeros((n + 1,), ret.dtype).at[
+            jnp.where(st.seg_start, st.seg_id, n)].set(base_rep)
+        base = base_seg[st.seg_id]                        # per sorted op
+        if op == "faa":
+            fetched = base + st.loc_fetched
+            success = jnp.ones((n,), bool)
+        elif op in ("min", "max"):
+            comb = jnp.minimum if op == "min" else jnp.maximum
+            fetched = comb(base, st.loc_fetched)
+            success = jnp.ones((n,), bool)
+        elif op == "swp":
+            fetched = jnp.where(st.seg_start, base, st.loc_fetched)
+            success = jnp.ones((n,), bool)
+        else:  # cas (uniform): the local chain assumed base == expected
+            exp = jnp.asarray(expected, base.dtype)
+            live = base == exp
+            fetched = jnp.where(live, st.loc_fetched, base)
+            success = live & st.loc_success
+        valid = st.sidx < stage.m_global
+        fetched = jnp.where(valid, fetched, jnp.zeros((), fetched.dtype))
+        success = success & valid
+        return fetched[st.inv], success[st.inv]
 
 
 # ---------------------------------------------------------------------------
@@ -455,13 +460,16 @@ def execute_sharded(table: Array, indices: Array, values: Array, op: str,
     zero_s = jnp.zeros((n,), bool)
 
     if strategy == "dense":
-        dense = jnp.zeros((m_global + 1,), values.dtype
-                          ).at[gidx].add(values)[:-1]
-        delta = jax.lax.psum_scatter(dense, shard_axes, scatter_dimension=0,
-                                     tiled=True)
-        if rep_axes:
-            delta = jax.lax.psum(delta, rep_axes)
-        result = RmwResult(table + delta, zero_f, zero_s)
+        with jax.named_scope("exchange.precombine"):
+            dense = jnp.zeros((m_global + 1,), values.dtype
+                              ).at[gidx].add(values)[:-1]
+        with jax.named_scope("exchange.send"):
+            delta = jax.lax.psum_scatter(dense, shard_axes,
+                                         scatter_dimension=0, tiled=True)
+            if rep_axes:
+                delta = jax.lax.psum(delta, rep_axes)
+        with jax.named_scope("exchange.resolve"):
+            result = RmwResult(table + delta, zero_f, zero_s)
         if collect_stats:  # dense has no exchange levels: L = 0
             return result, _contention_stats(
                 gidx, m_loc=m_loc, m_global=m_global, shard_axes=shard_axes,
@@ -522,16 +530,17 @@ def execute_sharded(table: Array, indices: Array, values: Array, op: str,
         stages.append(stage)
 
     # --- resolve at the owner ---------------------------------------------
-    shard = jax.lax.axis_index(shard_axes)
-    row = local_row(cur_idx, shard, m_loc, m_global)
-    res = rmw_engine.execute_backend(
-        table, row, cur_vals, op,
-        None if op != "cas" else jnp.asarray(expected, table.dtype),
-        backend=backend, spec=spec, need_fetched=need_fetched)
-    new_table = res.table
-    if rep_axes:
-        # only replica rank 0 received real ops; broadcast its shard update
-        new_table = table + jax.lax.psum(new_table - table, rep_axes)
+    with jax.named_scope("exchange.resolve"):
+        shard = jax.lax.axis_index(shard_axes)
+        row = local_row(cur_idx, shard, m_loc, m_global)
+        res = rmw_engine.execute_backend(
+            table, row, cur_vals, op,
+            None if op != "cas" else jnp.asarray(expected, table.dtype),
+            backend=backend, spec=spec, need_fetched=need_fetched)
+        new_table = res.table
+        if rep_axes:
+            # only replica rank 0 received real ops; broadcast its update
+            new_table = table + jax.lax.psum(new_table - table, rep_axes)
 
     stats = None
     if collect_stats:
@@ -563,17 +572,21 @@ def _push_naive(gidx, vals, op, expected, axis, n_shards, m_loc, m_global,
     transfer per op), which the benchmark uses as the contention baseline.
     """
     n = gidx.shape[0]
-    dest = owner_shard(gidx, m_loc, n_shards)
-    valid = gidx < m_global
     cap = n
-    scratch = n_shards * cap
-    slotpos = _rank_slotpos(dest, valid, n_shards, cap)
-    send_idx = _scatter_padded(m_global, jnp.int32, slotpos, gidx, scratch)
-    send_val = _scatter_padded(0, vals.dtype, slotpos, vals, scratch)
-    recv_idx, recv_val = _route_pair(send_idx, send_val, axis, n_shards, cap)
-    if reverse:
-        recv_idx = _flip_lanes(recv_idx, n_shards, cap)
-        recv_val = _flip_lanes(recv_val, n_shards, cap)
+    with jax.named_scope("exchange.precombine"):    # packing only
+        dest = owner_shard(gidx, m_loc, n_shards)
+        valid = gidx < m_global
+        scratch = n_shards * cap
+        slotpos = _rank_slotpos(dest, valid, n_shards, cap)
+        send_idx = _scatter_padded(m_global, jnp.int32, slotpos, gidx,
+                                   scratch)
+        send_val = _scatter_padded(0, vals.dtype, slotpos, vals, scratch)
+    with jax.named_scope("exchange.send"):
+        recv_idx, recv_val = _route_pair(send_idx, send_val, axis, n_shards,
+                                         cap)
+        if reverse:
+            recv_idx = _flip_lanes(recv_idx, n_shards, cap)
+            recv_val = _flip_lanes(recv_val, n_shards, cap)
     comb = _Combined(order=jnp.arange(n), inv=jnp.arange(n), sidx=gidx,
                      sval=vals, seg_start=jnp.ones((n,), bool),
                      seg_id=jnp.arange(n, dtype=jnp.int32),
@@ -624,19 +637,22 @@ def _push_uncombined(gidx: Array, vals: Array, exps: Array, *,
     arrival-order contract.  Returns (slotpos, recv_idx, recv_val, recv_exp).
     """
     n = gidx.shape[0]
-    valid = gidx < m_global
     cap = n
-    slotpos = _rank_slotpos(dest, valid, n_dest, cap)
-    scratch = n_dest * cap
-    send_idx = _scatter_padded(m_global, jnp.int32, slotpos, gidx, scratch)
-    send_val = _scatter_padded(0, vals.dtype, slotpos, vals, scratch)
-    send_exp = _scatter_padded(0, exps.dtype, slotpos, exps, scratch)
-    recv_idx, recv_val, recv_exp = _route_cols(
-        (send_idx, send_val, send_exp), axis, n_dest, cap)
-    if reverse:
-        recv_idx, recv_val, recv_exp = (
-            _flip_lanes(c, n_dest, cap)
-            for c in (recv_idx, recv_val, recv_exp))
+    with jax.named_scope("exchange.precombine"):    # packing only
+        valid = gidx < m_global
+        slotpos = _rank_slotpos(dest, valid, n_dest, cap)
+        scratch = n_dest * cap
+        send_idx = _scatter_padded(m_global, jnp.int32, slotpos, gidx,
+                                   scratch)
+        send_val = _scatter_padded(0, vals.dtype, slotpos, vals, scratch)
+        send_exp = _scatter_padded(0, exps.dtype, slotpos, exps, scratch)
+    with jax.named_scope("exchange.send"):
+        recv_idx, recv_val, recv_exp = _route_cols(
+            (send_idx, send_val, send_exp), axis, n_dest, cap)
+        if reverse:
+            recv_idx, recv_val, recv_exp = (
+                _flip_lanes(c, n_dest, cap)
+                for c in (recv_idx, recv_val, recv_exp))
     return slotpos, recv_idx, recv_val, recv_exp
 
 
@@ -679,14 +695,15 @@ def _execute_cas_perop(table: Array, indices: Array, values: Array,
             dest=dest_r, m_global=m_global, reverse=reverse)
         stages.append((rep_axes, n_rep, n2, slotpos))
 
-    shard = jax.lax.axis_index(shard_axes)
-    row = local_row(cur_idx, shard, m_loc, m_global)
-    res = rmw_engine.execute_backend(table, row, cur_val, "cas", cur_exp,
-                                     backend="serialized", spec=spec,
-                                     need_fetched=need_fetched)
-    new_table = res.table
-    if rep_axes:                    # broadcast replica rank 0's update
-        new_table = table + jax.lax.psum(new_table - table, rep_axes)
+    with jax.named_scope("exchange.resolve"):
+        shard = jax.lax.axis_index(shard_axes)
+        row = local_row(cur_idx, shard, m_loc, m_global)
+        res = rmw_engine.execute_backend(table, row, cur_val, "cas", cur_exp,
+                                         backend="serialized", spec=spec,
+                                         need_fetched=need_fetched)
+        new_table = res.table
+        if rep_axes:                # broadcast replica rank 0's update
+            new_table = table + jax.lax.psum(new_table - table, rep_axes)
 
     stats = None
     if collect_stats:
@@ -708,11 +725,12 @@ def _execute_cas_perop(table: Array, indices: Array, values: Array,
 
     bases = res.fetched.astype(values.dtype)
     for axis, n_dest, cap, slotpos in reversed(stages):
-        if reverse:                 # undo the receive-side flip per level
-            bases = _flip_lanes(bases, n_dest, cap)
-        ret = _route_flat(bases, axis, n_dest, cap)
-        ret = jnp.concatenate([ret, jnp.zeros((1,), ret.dtype)])
-        bases = ret[slotpos]        # scratch -> 0
+        with jax.named_scope("exchange.return"):
+            if reverse:             # undo the receive-side flip per level
+                bases = _flip_lanes(bases, n_dest, cap)
+            ret = _route_flat(bases, axis, n_dest, cap)
+            ret = jnp.concatenate([ret, jnp.zeros((1,), ret.dtype)])
+            bases = ret[slotpos]    # scratch -> 0
     valid = gidx < m_global
     fetched = jnp.where(valid, bases, zero_f)
     success = valid & (bases == exp.astype(values.dtype))

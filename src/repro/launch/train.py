@@ -94,10 +94,8 @@ def train(arch: str, *, steps: int = 100, seq_len: int = 256,
         batch = synthetic_batch(data_cfg, step, **bkw)
         t0 = time.time()
         # the span is the per-step profiler hook: wall_s lands in the event
-        # stream, and under enable(annotate=True) the step also shows up as
-        # a named range in a jax.profiler trace
-        with telemetry.annotation(f"train.step/{step}"), \
-                telemetry.span("train.step", step=step, arch=arch):
+        # stream, and the step is a named range in a jax.profiler trace
+        with telemetry.span("train.step", step=step, arch=arch):
             params, opt_state, metrics = step_fn(params, opt_state, batch)
             metrics = {k: float(v) for k, v in metrics.items()}
         dt = time.time() - t0
@@ -222,14 +220,11 @@ def main() -> None:
                          "drift telemetry); optional value = state file the "
                          "tuned spec persists/restores through (same as "
                          "REPRO_TUNING)")
-    ap.add_argument("--profile-annotations", action="store_true",
-                    help="open jax.profiler.TraceAnnotation regions around "
-                         "steps and atomics dispatch (needs --telemetry)")
     args = ap.parse_args()
     if args.telemetry:
         sink = (telemetry.RingBuffer() if args.telemetry == "ring"
                 else telemetry.JsonlWriter(args.telemetry))
-        telemetry.enable(sink, annotate=args.profile_annotations)
+        telemetry.enable(sink)
     else:
         telemetry.enable_from_env()
     chaos = FaultPlan.from_spec(args.chaos) if args.chaos else None

@@ -2,8 +2,9 @@
 
 The observability layer every tier of the atomics stack reports into:
 
-* `record` / `span` / `annotation` — the instrumentation primitives
-  (near-zero cost disabled; see `repro.telemetry.core`).
+* `record` / `span` — the instrumentation primitives (near-zero cost
+  disabled; every span is also a profiler span, see
+  `repro.telemetry.core`).
 * `enable` / `disable` / `capture` / `enable_from_env` — stream control.
 * `RingBuffer` / `JsonlWriter` / `Counters` — the pluggable sinks.
 * `repro.telemetry.drift` — predicted-vs-measured aggregation over the
@@ -53,8 +54,7 @@ Event catalogue (the schema table lives in README "Observability"):
 """
 
 from repro.telemetry.core import (Counters, JsonlWriter, RingBuffer, Sink,
-                                  Span, add_sink, annotation,
-                                  annotations_enabled, capture, disable,
+                                  Span, add_sink, capture, disable,
                                   enable, enable_from_env, enabled,
                                   flush_ring, read_jsonl, record,
                                   record_event, remove_sink, ring_events,
@@ -63,7 +63,7 @@ from repro.telemetry.core import (Counters, JsonlWriter, RingBuffer, Sink,
 
 __all__ = [
     "Counters", "JsonlWriter", "RingBuffer", "Sink", "Span",
-    "add_sink", "annotation", "annotations_enabled", "capture", "disable",
+    "add_sink", "capture", "disable",
     "enable", "enable_from_env", "enabled", "flush_ring", "read_jsonl",
     "record", "record_event", "remove_sink", "ring_events", "sinks",
     "span", "sync_enabled", "telemetry_dir",

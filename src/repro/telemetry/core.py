@@ -10,11 +10,16 @@ into one process-wide event stream:
   to every installed sink.  **Near-zero cost when disabled**: the hot-path
   guard is a single module-global boolean (`enabled()`), so instrumented
   code pays one branch per call site when telemetry is off.
-* ``span(name, **fields)`` — timing context manager.  It *always* measures
-  (``perf_counter`` on enter/exit, exposing ``.wall_s``) so benchmarks can
-  use it as their one clock, and records an event only when enabled.  This
-  is the single warmup-free timing convention shared by ``benchmarks/``
-  and the production paths.
+* ``span(name, **fields)`` — timing context manager and the one tracing
+  mechanism.  It *always* measures (``perf_counter`` on enter/exit,
+  exposing ``.wall_s``) so benchmarks can use it as their one clock, and it
+  always opens a `jax.profiler.TraceAnnotation` whose arguments are the
+  span's fields: inside a profiler trace the span lands on the device
+  trace's clock (read back by ``bench/scopes.py``); outside one the
+  annotation costs one constructor call.  It records an event only when
+  the stream is enabled.  Counts known only at the end go in with
+  ``sp.set(...)``.  Open spans on eager calls only — inside a function
+  being traced by ``jit`` use ``jax.named_scope``.
 * sinks — :class:`RingBuffer` (bounded in-memory, tests), ``JsonlWriter``
   (one JSON object per line, offline analysis / the report CLI),
   ``Counters`` (streaming aggregation, no retention).
@@ -44,6 +49,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 #: env var: a JSONL path (or "ring") enabling telemetry at process start
 #: for unmodified callers — the observability sibling of ``REPRO_CHAOS``
 TELEMETRY_ENV = "REPRO_TELEMETRY"
@@ -52,7 +59,6 @@ _lock = threading.Lock()
 _sinks: Tuple["Sink", ...] = ()
 _enabled: bool = False          # the one hot-path guard
 _sync: bool = False             # block_until_ready around measured calls
-_annotate: bool = False         # jax.profiler.TraceAnnotation at dispatch
 
 
 # ---------------------------------------------------------------------------
@@ -197,32 +203,23 @@ def sync_enabled() -> bool:
     return _enabled and _sync
 
 
-def annotations_enabled() -> bool:
-    """True when dispatch sites should open `jax.profiler.TraceAnnotation`
-    scopes (named regions in a profiler trace)."""
-    return _enabled and _annotate
-
-
-def enable(*sinks: Sink, sync: bool = False, annotate: bool = False) -> None:
+def enable(*sinks: Sink, sync: bool = False) -> None:
     """Install ``sinks`` (replacing any current set) and turn the stream on.
 
     ``sync=True`` makes instrumented dispatch sites block until results are
     ready before reading the clock — accurate measured-vs-predicted events
     at the price of de-pipelining; leave False in production.
-    ``annotate=True`` additionally opens ``jax.profiler.TraceAnnotation``
-    regions around engine dispatch / exchange collectives / train steps.
     """
-    global _sinks, _enabled, _sync, _annotate
+    global _sinks, _enabled, _sync
     with _lock:
         _sinks = tuple(sinks) or (RingBuffer(),)
         _sync = bool(sync)
-        _annotate = bool(annotate)
         _enabled = True
 
 
 def disable() -> None:
     """Turn the stream off and close the installed sinks."""
-    global _sinks, _enabled, _sync, _annotate
+    global _sinks, _enabled, _sync
     with _lock:
         for s in _sinks:
             try:
@@ -232,24 +229,20 @@ def disable() -> None:
         _sinks = ()
         _enabled = False
         _sync = False
-        _annotate = False
 
 
-def add_sink(sink: Sink, *, sync: Optional[bool] = None,
-             annotate: Optional[bool] = None) -> None:
+def add_sink(sink: Sink, *, sync: Optional[bool] = None) -> None:
     """Attach ``sink`` *alongside* any installed sinks and turn the stream
-    on (contrast `enable`, which replaces the sink set).  ``sync``/
-    ``annotate`` only ever widen the current flags — a live consumer (the
-    tuning controller) must not silently strip another consumer's settings.
-    Pair with `remove_sink`."""
-    global _sinks, _enabled, _sync, _annotate
+    on (contrast `enable`, which replaces the sink set).  ``sync`` only
+    ever widens the current flag — a live consumer (the tuning controller)
+    must not silently strip another consumer's settings.  Pair with
+    `remove_sink`."""
+    global _sinks, _enabled, _sync
     with _lock:
         if sink not in _sinks:
             _sinks = _sinks + (sink,)
         if sync is not None:
             _sync = _sync or bool(sync)
-        if annotate is not None:
-            _annotate = _annotate or bool(annotate)
         _enabled = True
 
 
@@ -257,14 +250,13 @@ def remove_sink(sink: Sink, *, close: bool = False) -> bool:
     """Detach one sink installed via `add_sink`/`enable`.  When the last
     sink goes, the stream turns fully off (flags reset).  Returns True if
     the sink was installed."""
-    global _sinks, _enabled, _sync, _annotate
+    global _sinks, _enabled, _sync
     with _lock:
         had = any(s is sink for s in _sinks)
         _sinks = tuple(s for s in _sinks if s is not sink)
         if not _sinks:
             _enabled = False
             _sync = False
-            _annotate = False
     if had and close:
         try:
             sink.close()
@@ -278,8 +270,7 @@ def sinks() -> Tuple[Sink, ...]:
 
 
 @contextlib.contextmanager
-def capture(sink: Optional[Sink] = None, *, sync: bool = False,
-            annotate: bool = False):
+def capture(sink: Optional[Sink] = None, *, sync: bool = False):
     """Scoped enable: install ``sink`` (default: a fresh :class:`RingBuffer`)
     *in addition to* any already-installed sinks, yield it, and restore the
     previous state on exit.  The standard test/benchmark spelling::
@@ -288,19 +279,18 @@ def capture(sink: Optional[Sink] = None, *, sync: bool = False,
             atomics.execute(...)
         events = buf.events
     """
-    global _sinks, _enabled, _sync, _annotate
+    global _sinks, _enabled, _sync
     target = sink if sink is not None else RingBuffer()
     with _lock:
-        prev = (_sinks, _enabled, _sync, _annotate)
+        prev = (_sinks, _enabled, _sync)
         _sinks = prev[0] + (target,)
         _sync = bool(sync) or _sync
-        _annotate = bool(annotate) or _annotate
         _enabled = True
     try:
         yield target
     finally:
         with _lock:
-            _sinks, _enabled, _sync, _annotate = prev
+            _sinks, _enabled, _sync = prev
         if sink is None:
             pass                      # caller keeps the buffer; nothing to close
         # an explicitly passed sink stays open — its owner closes it
@@ -333,23 +323,36 @@ def record_event(ev: Dict[str, Any]) -> None:
 
 
 class Span:
-    """Timing scope: measures wall seconds between enter and exit (always —
-    ``.wall_s`` is valid whether or not the stream is on) and records one
-    ``{event: name, wall_s: ...}`` event when enabled."""
+    """Timing scope and profiler span.
 
-    __slots__ = ("name", "fields", "wall_s", "_t0")
+    Measures wall seconds between enter and exit (always — ``.wall_s`` is
+    valid whether or not the stream is on), opens a
+    `jax.profiler.TraceAnnotation` named ``name`` with the fields as its
+    arguments, and records one ``{event: name, wall_s, ok, **fields}`` event
+    when the stream is enabled.  ``set(**counts)`` adds fields known only
+    at the end (a level count read back from the device): they join the
+    event and the annotation's arguments, on the span's own time."""
+
+    __slots__ = ("name", "fields", "wall_s", "_t0", "_ann")
 
     def __init__(self, name: str, fields: Dict[str, Any]):
         self.name = name
         self.fields = fields
         self.wall_s: Optional[float] = None
+        self._ann = _TraceAnnotation(name, **fields)
 
     def __enter__(self) -> "Span":
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
+    def set(self, **counts) -> None:
+        self.fields.update(counts)
+        self._ann.set_metadata(**counts)
+
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.wall_s = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
         if _enabled:
             record(self.name, wall_s=self.wall_s,
                    ok=exc_type is None, **self.fields)
@@ -359,17 +362,8 @@ class Span:
 def span(name: str, **fields) -> Span:
     """``with telemetry.span("train.step", step=i) as sp: ...`` — see
     :class:`Span`.  ``sp.wall_s`` is the one clock benchmarks and
-    production paths share."""
+    production paths share; the profiler sees the same span."""
     return Span(name, fields)
-
-
-def annotation(name: str):
-    """A `jax.profiler.TraceAnnotation` scope when annotations are enabled,
-    else a no-op context — cheap enough to leave on dispatch sites."""
-    if not (_enabled and _annotate):
-        return contextlib.nullcontext()
-    import jax.profiler
-    return jax.profiler.TraceAnnotation(name)
 
 
 # ---------------------------------------------------------------------------

@@ -129,6 +129,102 @@ def test_span_records_failure_flag():
     assert buf.events[0]["ok"] is False
 
 
+def test_span_set_adds_end_counts_to_the_event():
+    with telemetry.capture() as buf:
+        with telemetry.span("x", n=4) as sp:
+            sp.set(levels=7)
+    (ev,) = buf.events
+    assert ev["n"] == 4 and ev["levels"] == 7 and sp.fields["levels"] == 7
+
+
+def test_span_records_nothing_when_the_stream_is_off():
+    with telemetry.capture() as buf:
+        pass
+    with telemetry.span("x", n=4) as sp:
+        sp.set(levels=7)
+    assert len(buf) == 0 and telemetry.sinks() == ()
+    assert sp.wall_s is not None
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under the profiler; its result and the host events of
+    the trace as ``{name: [stats dict, ...]}``."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+               for f in fs if f.endswith(".xplane.pb")]
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                events.setdefault(ev.name, []).append(dict(ev.stats))
+    return out, events
+
+
+def test_span_is_a_profiler_span_with_its_fields(tmp_path):
+    def run():
+        with telemetry.span("layer.phase", n=4, op="faa") as sp:
+            sp.set(levels=7)
+    _, events = _profiled(tmp_path, run)
+    (stats,) = events["layer.phase"]
+    assert stats == {"n": 4, "op": "faa", "levels": 7}
+
+
+def test_eager_execute_spans_in_the_trace_and_results_unchanged(tmp_path):
+    """`atomics.execute` is bit-identical with the profiler on and off, and
+    its spans carry the batch size, the op and the backend picked."""
+    from repro.core import rmw_engine
+    tbl = atomics.AtomicTable(jnp.zeros((512,), jnp.int32))
+    op = _faa(256, 512, seed=5)
+    off = atomics.execute(tbl, op)
+    on, events = _profiled(tmp_path, lambda: atomics.execute(tbl, op))
+    for a, b in ((off.table.data, on.table.data), (off.fetched, on.fetched),
+                 (off.success, on.success)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    picked = rmw_engine.select_backend("faa", 256, 512, dtype=jnp.int32)
+    (ex,) = events["atomics.execute"]
+    assert ex == {"n": 256, "op": "faa", "backend": picked}
+    assert len(events["atomics.select"]) == 1
+    assert events["atomics.dispatch"] == [{"backend": picked}]
+
+
+def test_stats_path_spans_select_and_dispatch(tmp_path):
+    tbl = atomics.AtomicTable(jnp.zeros((64,), jnp.int32))
+    res, events = _profiled(tmp_path, lambda: atomics.execute(
+        tbl, _faa(32, 64), collect_stats=True))
+    assert res.stats is not None
+    assert len(events["atomics.select"]) == 1
+    (ex,) = events["atomics.execute"]
+    assert events["atomics.dispatch"] == [{"backend": ex["backend"]}]
+
+
+def test_no_span_inside_a_traced_function(tmp_path):
+    @jax.jit
+    def step(data, idx, vals):
+        return atomics.execute(data, atomics.Faa(idx, vals)).table.data
+    op = _faa(16, 32)
+    _, events = _profiled(tmp_path, lambda: step(
+        jnp.zeros((32,), jnp.int32), op.indices, op.values))
+    assert not {"atomics.execute", "atomics.select",
+                "atomics.dispatch"} & set(events)
+
+
+def test_bfs_traversal_span_carries_its_counts(tmp_path):
+    from repro.core import bfs
+    src = np.array([0, 1, 1, 2, 3, 4], np.int32)
+    dst = np.array([1, 0, 2, 1, 4, 3], np.int32)
+    res, events = _profiled(tmp_path, lambda: bfs.bfs(src, dst, 5, root=0))
+    (stats,) = events["bfs.traversal"]
+    assert stats["levels"] == res.levels == 3
+    assert stats["edges_traversed"] == res.edges_traversed
+
+
 def test_enable_from_env(tmp_path, monkeypatch):
     monkeypatch.delenv(telemetry.TELEMETRY_ENV, raising=False)
     assert telemetry.enable_from_env() is False
