@@ -13,12 +13,12 @@ semantics).  This module provides:
   For FAA/SWP/MIN/MAX and for CAS with a uniform expected value it returns
   exactly the serialized result (property-tested in tests/test_rmw.py).
 
-Shared helpers (`segmented_scan`, the argsort arrival rank behind
+Shared helpers (`segmented_scan`, the sort-based arrival rank behind
 `repro.atomics.arrival_rank`) are reused by the MoE dispatch
 (position-in-expert counters = FAA fetch results) and the BFS example
 (parent updates = CAS/SWP).
 
-This module holds the *sort* (argsort + segmented scan) implementation and
+This module holds the *sort* (sort + segmented scan) implementation and
 the serialized oracle — implementation building blocks for the engine
 (`core.rmw_engine`) and the unified front-end (`repro.atomics`, the one
 public entry).  The PR-3 deprecation shims (the ``rmw()`` facade and the
@@ -97,17 +97,28 @@ def _exclusive_from_inclusive(incl: Array, values: Array, seg_start: Array,
     return jnp.where(first, jnp.asarray(identity, incl.dtype), shifted)
 
 
-def _sort_by_index(indices: Array, *arrays: Array):
-    order = jnp.argsort(indices, stable=True)
-    inv = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0]))
-    sorted_idx = indices[order]
+def _sort_by_index(indices: Array, *payloads: Array):
+    """Stable sort of the batch by slot, the payloads riding along.
+
+    One ``lax.sort`` carries the payloads and the arrival order, so no
+    permutation is applied by a gather: on the TPU a random gather of the
+    batch costs several sorts of it."""
+    iota = jnp.arange(indices.shape[0], dtype=jnp.int32)
+    idx_s, order, *sorted_payloads = jax.lax.sort(
+        (indices, iota, *payloads), num_keys=1, is_stable=True)
     seg_start = jnp.concatenate(
-        [jnp.ones((1,), bool), sorted_idx[1:] != sorted_idx[:-1]])
-    return order, inv, sorted_idx, seg_start, tuple(a[order] for a in arrays)
+        [jnp.ones((1,), bool), idx_s[1:] != idx_s[:-1]])
+    return order, idx_s, seg_start, tuple(sorted_payloads)
+
+
+def _unsort(order: Array, *arrays: Array):
+    """Put arrays in sorted order back in arrival order: a sort keyed on
+    ``order``, a permutation, so its keys are unique."""
+    return tuple(jax.lax.sort((order, *arrays), num_keys=1)[1:])
 
 
 def _arrival_rank_argsort(keys: Array) -> Array:
-    """Per-element arrival order among equal keys (0-based), via argsort.
+    """Per-element arrival order among equal keys (0-based), via a sort.
 
     Semantically this is the fetch result of FAA(counter[key], 1) executed in
     element order — the exact primitive MoE dispatch uses to assign each token
@@ -115,10 +126,11 @@ def _arrival_rank_argsort(keys: Array) -> Array:
     lives in the engine; `repro.atomics.arrival_rank` is the one public
     spelling (this path is its ``num_keys=None`` fallback).
     """
-    order, inv, _, seg_start, _ = _sort_by_index(keys)
+    order, _, seg_start, _ = _sort_by_index(keys)
     ones = jnp.ones_like(keys, dtype=jnp.int32)
     incl = segmented_scan(ones, seg_start, jnp.add)
-    return (incl - 1)[inv]
+    (rank,) = _unsort(order, incl - 1)
+    return rank
 
 
 # ---------------------------------------------------------------------------
@@ -201,8 +213,7 @@ def rmw_combining(table: Array, indices: Array, values: Array, op: str,
         return _cas_uniform(table, indices, values, expected)
 
     with jax.named_scope("rmw.sort"):
-        order, inv, idx_s, seg_start, (val_s,) = _sort_by_index(indices,
-                                                                values)
+        order, idx_s, seg_start, (val_s,) = _sort_by_index(indices, values)
     with jax.named_scope("rmw.gather"):
         base = table[idx_s]
 
@@ -218,7 +229,7 @@ def rmw_combining(table: Array, indices: Array, values: Array, op: str,
             padded = jnp.concatenate([table, table[:1]], axis=0)
             new_table = padded.at[write_idx].set(val_s)[:-1]
         with jax.named_scope("rmw.unsort"):
-            fetched = fetched_s[inv]
+            (fetched,) = _unsort(order, fetched_s)
         return RmwResult(new_table, fetched, jnp.ones((n,), bool))
 
     comb = _combine_fn(op)
@@ -235,7 +246,7 @@ def rmw_combining(table: Array, indices: Array, values: Array, op: str,
         else:
             new_table = table.at[indices].max(values)
     with jax.named_scope("rmw.unsort"):
-        fetched = fetched_s[inv]
+        (fetched,) = _unsort(order, fetched_s)
     return RmwResult(new_table, fetched, jnp.ones((n,), bool))
 
 
@@ -244,19 +255,18 @@ def _cas_uniform(table: Array, indices: Array, values: Array,
     """CAS with one shared expected value: first collider at a matching slot
     wins; later colliders observe the winner's value and fail (paper's BFS
     pattern: cas(parent[v], -1, u)).  1-D tables only."""
-    exp_all = jnp.broadcast_to(jnp.asarray(expected, table.dtype), values.shape)
+    exp = jnp.asarray(expected, table.dtype)
     with jax.named_scope("rmw.sort"):
-        order, inv, idx_s, seg_start, (val_s, exp_s) = _sort_by_index(
-            indices, values, exp_all)
+        order, idx_s, seg_start, (val_s,) = _sort_by_index(indices, values)
     with jax.named_scope("rmw.gather"):
         base = table[idx_s]
     with jax.named_scope("rmw.scan"):
-        matches = base == exp_s  # slot held `expected` before the batch
+        matches = base == exp  # slot held `expected` before the batch
         # Serialized chain semantics: ops succeed while the slot still
         # holds `expected`.  Writing desired == expected keeps the chain
         # alive; the first op writing desired != expected ("break op")
         # ends it.
-        eq = (val_s == exp_s).astype(jnp.int32)
+        eq = (val_s == exp).astype(jnp.int32)
         incl_alive = segmented_scan(eq, seg_start, jnp.minimum)
         alive_excl = _exclusive_from_inclusive(incl_alive, eq, seg_start, 1
                                                ).astype(bool)
@@ -274,7 +284,7 @@ def _cas_uniform(table: Array, indices: Array, values: Array,
         padded = jnp.concatenate([table, table[:1]], axis=0)
         new_table = padded.at[write_idx].set(val_s)[:-1]
     with jax.named_scope("rmw.unsort"):
-        fetched, success = fetched_s[inv], success_s[inv]
+        fetched, success = _unsort(order, fetched_s, success_s)
     return RmwResult(new_table, fetched, success)
 
 

@@ -1,6 +1,7 @@
 """Property tests: the combining RMW is serialized-equivalent (paper core)."""
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -10,6 +11,7 @@ except ImportError:  # container image: fall back to the local shim
     from _hypothesis_shim import given, settings, strategies as st
 
 from repro.atomics import arrival_rank
+from repro.core import rmw as rmw_mod
 from repro.core.rmw import rmw_combining, rmw_serialized, segmented_scan
 
 SET = settings(max_examples=30, deadline=None)
@@ -146,7 +148,6 @@ def test_blocked_segmented_scan_matches_one_scan(combine):
     """Past SCAN_BLOCK elements the scan runs block by block, with segments
     that cross block edges, an unflagged element 0 and a ragged tail; the
     result is a per-segment accumulate's."""
-    from repro.core import rmw as rmw_mod
     n = 2 * rmw_mod.SCAN_BLOCK + 77
     r = np.random.default_rng(5)
     v = r.integers(-50, 51, n).astype(np.int32)
@@ -159,3 +160,79 @@ def test_blocked_segmented_scan_matches_one_scan(combine):
     want = np.concatenate([ufunc.accumulate(seg)
                            for seg in np.split(v, starts[1:])])
     np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def _skewed_batch(seed, n=rmw_mod.SCAN_BLOCK + 3, m=1024):
+    """``n`` keys over ``m`` slots, one slot taking about a tenth of them;
+    ``n`` is no power of two and longer than one scan block."""
+    r = np.random.default_rng(seed)
+    idx = r.integers(0, m, n)
+    idx[r.random(n) < 0.1] = 7
+    return m, jnp.asarray(idx, jnp.int32)
+
+
+@pytest.mark.parametrize("op,dtype", [("faa", jnp.int32),
+                                      ("faa", jnp.float32),
+                                      ("swp", jnp.int32)])
+def test_combining_equals_serialized_long_skewed(op, dtype):
+    """Equal keys keep their arrival order through the sort: SWP's fetched
+    values and FAA's running sums change with any other tie order.  The
+    float values are quarters under 2^22, so every sum is exact in float32
+    and a difference can come only from the order of the ops."""
+    m, idx = _skewed_batch(11)
+    r = np.random.default_rng(12)
+    vals = jnp.asarray(r.integers(-400, 401, idx.shape[0]) / 4, dtype)
+    table = jnp.asarray(r.integers(-8, 9, m), dtype)
+    a = rmw_serialized(table, idx, vals, op)
+    b = rmw_combining(table, idx, vals, op)
+    np.testing.assert_array_equal(a.table, b.table)
+    np.testing.assert_array_equal(a.fetched, b.fetched)
+    np.testing.assert_array_equal(a.success, b.success)
+
+
+def test_arrival_rank_sort_matches_sort_free_long_skewed():
+    m, keys = _skewed_batch(13)
+    np.testing.assert_array_equal(arrival_rank(keys), arrival_rank(keys, m))
+
+
+def _primitives(jaxpr, out):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it (jit, scan,
+    cond bodies), as (primitive name, shapes of its operands)."""
+    for eqn in jaxpr.eqns:
+        out.append((eqn.primitive.name,
+                    [getattr(v.aval, "shape", ()) for v in eqn.invars]))
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    _primitives(sub.jaxpr, out)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    _primitives(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("op", ["faa", "swp", "min", "max", "cas",
+                                "arrival_rank"])
+def test_sort_backend_permutes_by_sorting(op):
+    """The batch reaches sorted order and goes back by two sorts that carry
+    it; the only gather and the only batch-long scatter touch the table."""
+    n = 100
+    table = jnp.zeros((64,), jnp.int32)
+    idx = (jnp.arange(n, dtype=jnp.int32) * 7) % 64
+    vals = jnp.ones((n,), jnp.int32)
+    if op == "arrival_rank":
+        jaxpr = jax.make_jaxpr(rmw_mod._arrival_rank_argsort)(idx)
+        n_gather = n_scatter = 0
+    else:
+        exp = jnp.int32(0) if op == "cas" else None
+        jaxpr = jax.make_jaxpr(
+            lambda t, i, v: rmw_combining(t, i, v, op, exp))(table, idx, vals)
+        n_gather = n_scatter = 1
+    prims = _primitives(jaxpr.jaxpr, [])
+    # operand 0 is the array read or written: the table (plus SWP's and
+    # CAS's scratch row); one-element scatters set a scan's first flag
+    gathers = [s[0] for p, s in prims if p == "gather"]
+    scatters = [s[0] for p, s in prims
+                if p.startswith("scatter") and s[2][:1] == (n,)]
+    assert all(shape in ((64,), (65,)) for shape in gathers + scatters)
+    assert (len(gathers), len(scatters)) == (n_gather, n_scatter)
+    assert sum(p == "sort" for p, _ in prims) == 2
