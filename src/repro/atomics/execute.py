@@ -40,6 +40,7 @@ from repro import telemetry
 from repro.telemetry import core as _tcore
 from repro.atomics import contracts as _contracts
 from repro.atomics import stats as _cstats
+from repro.atomics.layout import INT32_SLOTS
 from repro.atomics.ops import AtomicOp
 from repro.atomics.table import AtomicTable
 from repro.core import rmw as rmw_mod
@@ -163,6 +164,11 @@ def _dispatch_one(table: AtomicTable, op: AtomicOp, *, need_fetched: bool,
                 f"sharded tier only, but the table is local — wrap it as "
                 f"AtomicTable(data, axis=...) (and call inside shard_map) "
                 f"or drop the sharded-tier arguments")
+        if table.data.shape[0] > INT32_SLOTS and \
+                op.indices.dtype == jnp.uint32:
+            raise ValueError(
+                f"the local tier addresses int32 slots, up to {INT32_SLOTS}; "
+                f"uint32 ids reach past that only on a sharded table")
         backend = rmw_engine.resolve_backend(
             table.data, op.indices, op.kind, op.expected, backend=backend,
             spec=spec, need_fetched=need_fetched)

@@ -44,6 +44,9 @@ import numpy as np
 Array = jax.Array
 AxisNames = Union[str, Tuple[str, ...], None]
 
+#: the most slots int32 ids address; a larger sharded table takes uint32 ids
+INT32_SLOTS = 2**31 - 1
+
 
 def norm_axes(axis: AxisNames) -> Tuple[str, ...]:
     """Normalize an axis spec (None / str / tuple) to a tuple of names."""
@@ -58,20 +61,35 @@ def norm_axes(axis: AxisNames) -> Tuple[str, ...]:
 # Owner-major arithmetic (the single home; rmw_sharded imports these)
 # ---------------------------------------------------------------------------
 
+def below(gidx: Array, m_global: int) -> Array:
+    """``gidx < m_global``; a bound past the id dtype's range (a 2^32-slot
+    table under uint32 ids) holds every id."""
+    if m_global > jnp.iinfo(gidx.dtype).max:
+        return jnp.ones(gidx.shape, bool)
+    return gidx < gidx.dtype.type(m_global)   # a Python int past 2^31 - 1
+                                              # would not convert
+
+
 def owner_shard(gidx: Array, m_local: int, n_shards: int) -> Array:
-    """Destination shard of each global slot id under owner-major layout.
+    """Destination shard (int32) of each global slot id under owner-major
+    layout, in the ids' own dtype (int32, or uint32 for tables past 2^31 - 1
+    slots).
 
     Valid ids map to ``g // m_local``; anything else (already remapped to
     ``>= m_global`` by the caller's OOR pass) clamps to the last shard,
     whose resolve pass drops it via the scratch row.
     """
-    return jnp.minimum(gidx // m_local, n_shards - 1)
+    return jnp.minimum(gidx // m_local, n_shards - 1).astype(jnp.int32)
 
 
 def local_row(gidx: Array, shard: Array, m_local: int, m_global: int) -> Array:
-    """Local row of a global slot on its owner; OOR ids -> the scratch row
-    (``m_local``), matching the engine's drop convention."""
-    return jnp.where(gidx < m_global, gidx - shard * m_local, m_local)
+    """Local row (int32) of a global slot on its owner; OOR ids -> the
+    scratch row (``m_local``), matching the engine's drop convention.  The
+    shard's base ``shard * m_local`` is taken in the ids' dtype, which holds
+    it for uint32 ids past 2^31."""
+    base = jnp.asarray(shard).astype(gidx.dtype) * gidx.dtype.type(m_local)
+    return jnp.where(below(gidx, m_global), gidx - base,
+                     m_local).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------

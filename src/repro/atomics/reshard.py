@@ -56,7 +56,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import telemetry
-from repro.atomics.layout import TableLayout, norm_axes
+from repro.atomics.layout import INT32_SLOTS, TableLayout, norm_axes
 from repro.atomics.table import AtomicTable
 
 Array = jax.Array
@@ -198,7 +198,13 @@ def plan_reshard(src: TableLayout, dst: TableLayout, *, dst_mesh,
         raise ValueError(
             f"slot-count changes are not migrations ({src.num_slots} -> "
             f"{dst.num_slots}); grow the table first, then reshard")
+    if path == "exchange" and src.num_slots > INT32_SLOTS:
+        raise ValueError(
+            f"path='exchange' computes int32 slot offsets, up to "
+            f"{INT32_SLOTS}; this table has {src.num_slots}: use "
+            f"'device_put'")
     feasible = bool(live and dst.is_sharded and src.is_sharded
+                    and src.num_slots <= INT32_SLOTS
                     and _same_device_set(src_mesh, dst_mesh))
     from repro.core import rmw_engine
     spec = spec or rmw_engine.default_spec()

@@ -50,6 +50,7 @@ from repro import telemetry
 from repro.telemetry import core as _tcore
 from repro.atomics import contracts as _contracts
 from repro.atomics import stats as _cstats
+from repro.atomics.layout import INT32_SLOTS
 from repro.atomics.ops import OP_KINDS, AtomicOp, Cas
 from repro.atomics.table import AtomicTable
 
@@ -444,6 +445,10 @@ def execute_until(table: Union[AtomicTable, Array],
             f"atomics.Cas(indices, values, expected=...)")
     kind = op0.kind
     n = int(op0.indices.shape[0])
+    if int(table.data.shape[0]) > INT32_SLOTS:
+        raise ValueError(
+            f"execute_until tracks pending slots as int32, up to "
+            f"{INT32_SLOTS}; this table has {int(table.data.shape[0])}")
     # contention estimator (repro.tuning): when a controller is running
     # and the caller passed no hint, serve the site's EWMA'd observed
     # distinct-slot count as the exchange selector's contention hint —

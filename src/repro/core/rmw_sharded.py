@@ -60,9 +60,15 @@ Strategies (`strategy=`):
                     crossover.
 
 Out-of-range indices are dropped (fetched 0 / success False), matching the
-engine's convention.  CAS supports both expected forms: the combinable
-*uniform* scalar (all strategies above) and **per-op expected arrays**,
-which cannot be pre-combined (the paper's "wasted work" case) and instead
+engine's convention.  Slot ids are int32, or uint32 for a table of more than
+2^31 - 1 slots (up to 2^32, where every uint32 id is valid): each id is split
+once into (owner, local row), the exchanges move int32 rows (and, to the
+hierarchical deputy, keys of ``n_outer * m_local`` slots), and the owner's
+scratch row ``m_local`` carries padding and out-of-range ops.
+
+CAS supports both expected forms: the combinable *uniform* scalar (all
+strategies above) and **per-op expected arrays**, which cannot be
+pre-combined (the paper's "wasted work" case) and instead
 route every op raw to its owner for a serialized-oracle pass
 (`_execute_cas_perop` — the owner-side form of the paper's §6.2
 remote-execution atomics).
@@ -82,7 +88,7 @@ from typing import NamedTuple, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from repro.atomics.layout import local_row, owner_shard
+from repro.atomics.layout import INT32_SLOTS, below, local_row, owner_shard
 from repro.core import collective_model, perf_model, rmw_engine
 from repro.core.collective_model import MeshAxis
 from repro.core.placement import Tier
@@ -113,9 +119,8 @@ def _axis_size(axis: AxisNames) -> int:
 class _Combined(NamedTuple):
     """Bookkeeping of one local pre-combine (all arrays in sorted order)."""
 
-    order: Array        # argsort of the input batch by global slot
-    inv: Array          # inverse permutation
-    sidx: Array         # sorted global slot ids (invalid == m_global)
+    inv: Array          # inverse of the sort by slot key
+    sidx: Array         # sorted slot keys (invalid == the level's bound)
     sval: Array         # sorted values
     seg_start: Array    # True at the first op of each same-slot group
     seg_id: Array       # compressed group index per op
@@ -157,7 +162,7 @@ def _combine(gidx: Array, vals: Array, op: str, expected, *,
     res = rmw_engine.execute_backend(ident, seg_id, sval, op, exp,
                                      backend=backend, spec=spec,
                                      need_fetched=need_fetched)
-    return _Combined(order=order, inv=inv, sidx=sidx, sval=sval,
+    return _Combined(inv=inv, sidx=sidx, sval=sval,
                      seg_start=seg_start, seg_id=seg_id, combined=res.table,
                      loc_fetched=res.fetched, loc_success=res.success)
 
@@ -170,7 +175,7 @@ class _Stage(NamedTuple):
     cap: int
     comb: _Combined
     slotpos: Array      # per-op packed buffer position (scratch if not rep)
-    m_global: int
+    bound: int          # keys below it are valid slots
     reverse: bool = False
 
 
@@ -205,18 +210,19 @@ def _scatter_padded(fill, dtype, slotpos: Array, x: Array,
 
 def _route_pair(send_idx: Array, send_val: Array, axis: AxisNames,
                 n_dest: int, cap: int) -> Tuple[Array, Array]:
-    """Move (slot id, combined value) rows with ONE all_to_all.
+    """Move (slot key, combined value) rows with ONE all_to_all.
 
-    4-byte value dtypes ride in the same buffer as the int32 ids (bitcast),
-    matching the cost model's single-launch ROW_BYTES pricing; wider dtypes
-    fall back to a second collective."""
+    4-byte value dtypes ride in the same int32 buffer as the 4-byte keys
+    (bitcast), matching the cost model's single-launch ROW_BYTES pricing;
+    wider dtypes fall back to a second collective."""
     if send_val.dtype.itemsize == 4:
+        ids = jax.lax.bitcast_convert_type(send_idx, jnp.int32)
         bits = jax.lax.bitcast_convert_type(send_val, jnp.int32)
-        packed = jnp.stack([send_idx, bits], axis=-1).reshape(n_dest, cap, 2)
+        packed = jnp.stack([ids, bits], axis=-1).reshape(n_dest, cap, 2)
         recv = jax.lax.all_to_all(packed, axis, split_axis=0,
                                   concat_axis=0).reshape(-1, 2)
-        return recv[:, 0], jax.lax.bitcast_convert_type(recv[:, 1],
-                                                        send_val.dtype)
+        return (jax.lax.bitcast_convert_type(recv[:, 0], send_idx.dtype),
+                jax.lax.bitcast_convert_type(recv[:, 1], send_val.dtype))
     recv_idx = jax.lax.all_to_all(send_idx.reshape(n_dest, cap), axis,
                                   split_axis=0, concat_axis=0).reshape(-1)
     recv_val = jax.lax.all_to_all(send_val.reshape(n_dest, cap), axis,
@@ -224,24 +230,24 @@ def _route_pair(send_idx: Array, send_val: Array, axis: AxisNames,
     return recv_idx, recv_val
 
 
-def _push(gidx: Array, vals: Array, op: str, expected, *, axis: AxisNames,
-          n_dest: int, dest: Array, cap: int, m_global: int,
+def _push(key: Array, vals: Array, op: str, expected, *, axis: AxisNames,
+          n_dest: int, route, cap: int, bound: int, next_bound: int,
           need_fetched: bool, backend: str, spec, reverse: bool = False
           ) -> Tuple[_Stage, Array, Array]:
-    """Pre-combine + route one level.  `dest` gives, per op, the destination
-    rank on `axis` (same for every op of a group).  Returns the stage record
-    and the received flat batch (source-rank-major — the arrival order;
-    descending source rank when ``reverse``)."""
+    """Pre-combine + route one level.  ``key`` names each op's slot in this
+    level's key space (valid below ``bound``); ``route(keys)`` gives, per
+    key, the destination rank on `axis` and the key in the receiver's space
+    (valid below ``next_bound``, which is also the padding).  Returns the
+    stage record and the received flat batch (source-rank-major — the
+    arrival order; descending source rank when ``reverse``)."""
     with jax.named_scope("exchange.precombine"):
-        st = _combine(gidx, vals, op, expected, need_fetched=need_fetched,
+        st = _combine(key, vals, op, expected, need_fetched=need_fetched,
                       backend=backend, spec=spec)
-        dest_s = dest[st.order]
-        valid = st.sidx < m_global
-        is_rep = st.seg_start & valid
+        dest_s, next_s = route(st.sidx)
+        is_rep = st.seg_start & below(st.sidx, bound)
         scratch = n_dest * cap
         slotpos = _rank_slotpos(dest_s, is_rep, n_dest, cap)
-        send_idx = _scatter_padded(m_global, jnp.int32, slotpos,
-                                   jnp.where(is_rep, st.sidx, m_global),
+        send_idx = _scatter_padded(next_bound, next_s.dtype, slotpos, next_s,
                                    scratch)
         send_val = _scatter_padded(0, vals.dtype, slotpos,
                                    st.combined[st.seg_id], scratch)
@@ -252,7 +258,7 @@ def _push(gidx: Array, vals: Array, op: str, expected, *, axis: AxisNames,
             recv_idx = _flip_lanes(recv_idx, n_dest, cap)
             recv_val = _flip_lanes(recv_val, n_dest, cap)
     stage = _Stage(axis=axis, n_dest=n_dest, cap=cap, comb=st,
-                   slotpos=slotpos, m_global=m_global, reverse=reverse)
+                   slotpos=slotpos, bound=bound, reverse=reverse)
     return stage, recv_idx, recv_val
 
 
@@ -288,7 +294,7 @@ def _pop(stage: _Stage, bases_recv: Array, op: str, expected
             live = base == exp
             fetched = jnp.where(live, st.loc_fetched, base)
             success = live & st.loc_success
-        valid = st.sidx < stage.m_global
+        valid = below(st.sidx, stage.bound)
         fetched = jnp.where(valid, fetched, jnp.zeros((), fetched.dtype))
         success = success & valid
         return fetched[st.inv], success[st.inv]
@@ -298,19 +304,19 @@ def _pop(stage: _Stage, bases_recv: Array, op: str, expected
 # Contention observatory (PR 10): stats from inside the combine passes
 # ---------------------------------------------------------------------------
 
-def _stage_level_counts(stages, m_global: int, all_axes: Tuple[str, ...]):
+def _stage_level_counts(stages, all_axes: Tuple[str, ...]):
     """Per-exchange-level combining efficiency from the stage bookkeeping.
 
     Each `_Stage` already materializes the collision structure of its
     pre-combine (`comb.seg_start` marks group representatives, `comb.sidx`
-    flags validity) — so ops-in / ops-out per level are free reductions over
-    arrays the protocol computed anyway.  Every logical op lives on exactly
-    one device at any level, so a psum over all participating axes counts
-    each exactly once.
+    below the stage's bound flags validity) — so ops-in / ops-out per level
+    are free reductions over arrays the protocol computed anyway.  Every
+    logical op lives on exactly one device at any level, so a psum over all
+    participating axes counts each exactly once.
     """
     level_in, level_out = [], []
     for st_ in stages:
-        v = st_.comb.sidx < m_global
+        v = below(st_.comb.sidx, st_.bound)
         level_in.append(jax.lax.psum(v.sum(dtype=jnp.int32), all_axes))
         level_out.append(jax.lax.psum(
             (st_.comb.seg_start & v).sum(dtype=jnp.int32), all_axes))
@@ -356,6 +362,41 @@ def _contention_stats(gidx: Array, *, m_loc: int, m_global: int,
 # ---------------------------------------------------------------------------
 # The distributed executor
 # ---------------------------------------------------------------------------
+
+def _check_width(indices: Array, m_loc: int, m_global: int, *, op: str,
+                 expected, collect_stats: bool) -> None:
+    """Refuse the tables and paths whose slot ids int32 cannot hold."""
+    if m_loc > INT32_SLOTS:
+        raise ValueError(f"a shard of {m_loc} rows is past the "
+                         f"{INT32_SLOTS} int32 rows the engine addresses")
+    if m_global <= INT32_SLOTS:
+        return
+    if indices.dtype != jnp.uint32:
+        raise ValueError(
+            f"a table of {m_global} slots, more than {INT32_SLOTS}, takes "
+            f"uint32 slot ids; got {indices.dtype}")
+    if collect_stats:
+        raise ValueError(
+            f"collect_stats counts slots in int32, up to {INT32_SLOTS}; "
+            f"this table has {m_global}")
+    if op == "cas" and jnp.ndim(expected) != 0:
+        raise ValueError(
+            f"per-op-expected CAS routes int32 slot ids, up to "
+            f"{INT32_SLOTS}; this table has {m_global}")
+
+
+def _global_keys(indices: Array, m_global: int) -> Array:
+    """Global slot ids, an out-of-range id replaced by ``m_global``: uint32
+    ids stay unsigned (at 2^32 slots every one is valid and none is
+    replaced), any other dtype becomes int32 as before."""
+    if indices.dtype == jnp.uint32:
+        if m_global > jnp.iinfo(jnp.uint32).max:
+            return indices
+        bound = jnp.uint32(m_global)
+        return jnp.where(indices < bound, indices, bound)
+    gidx = indices.astype(jnp.int32)
+    return jnp.where((gidx < 0) | (gidx >= m_global), m_global, gidx)
+
 
 def execute_sharded(table: Array, indices: Array, values: Array, op: str,
                     expected: Optional[Array] = None, *, axis: AxisNames,
@@ -425,6 +466,8 @@ def execute_sharded(table: Array, indices: Array, values: Array, op: str,
     m_loc = int(table.shape[0])
     m_global = m_loc * n_shards
     n = int(indices.shape[0])
+    _check_width(indices, m_loc, m_global, op=op, expected=expected,
+                 collect_stats=collect_stats)
 
     if op == "cas" and jnp.ndim(expected) != 0:
         # the owner resolve is a serialized-oracle pass by construction —
@@ -447,15 +490,25 @@ def execute_sharded(table: Array, indices: Array, values: Array, op: str,
             spec=spec, need_fetched=need_fetched,
             uniform_expected=True, replicas=n_rep,
             distinct_slots=distinct_slots)
-    if strategy == "hierarchical" and len(shard_axes) < 2:
+    with jax.named_scope("exchange.precombine"):
+        gidx = _global_keys(indices, m_global)
+    # the deputy's keys span n_outer * m_loc slots plus their padding
+    if strategy == "hierarchical" and (
+            len(shard_axes) < 2 or m_global // math.prod(sizes[1:])
+            > jnp.iinfo(gidx.dtype).max):
         strategy = "oneshot"
     if strategy == "dense" and not (op == "faa" and not need_fetched):
         raise ValueError("strategy='dense' is the pure-FAA table-only path")
+    if strategy == "dense" and m_global > INT32_SLOTS:
+        raise ValueError(
+            f"strategy='dense' builds a ({m_global} + 1,) {values.dtype} "
+            f"array on every chip, "
+            f"{(m_global + 1) * values.dtype.itemsize} bytes; it serves "
+            f"tables of up to {INT32_SLOTS} slots: use oneshot or "
+            f"hierarchical")
     # dense is pure commutative FAA — every arrival order yields the same
     # table, so reverse_ranks is trivially satisfied there.
 
-    gidx = indices.astype(jnp.int32)
-    gidx = jnp.where((gidx < 0) | (gidx >= m_global), m_global, gidx)
     zero_f = jnp.zeros((n,), values.dtype)
     zero_s = jnp.zeros((n,), bool)
 
@@ -477,64 +530,72 @@ def execute_sharded(table: Array, indices: Array, values: Array, op: str,
         return result
 
     # --- build the exchange pipeline (innermost level first) --------------
+    # every level's keys: global ids below m_global, the deputy's keys below
+    # n_outer * m_loc, and owner rows below m_loc (int32), whose bound is the
+    # owner's scratch row
+
+    def to_owner(n_dest, bound):
+        def route(k):
+            dest = owner_shard(k, m_loc, n_dest)
+            return dest, local_row(k, dest, m_loc, bound)
+        return route
+
     stages = []
-    cur_idx, cur_vals = gidx, values
     if strategy == "naive":
-        # route every op individually: pre-combining disabled by giving each
-        # op a unique routing key... simpler: one stage with cap = n and no
-        # combining is emulated by tagging ops with their position so no two
-        # share a group.  The owner still resolves in arrival order.
+        # route every op individually (no pre-combining); the owner still
+        # resolves in arrival order
         cur_idx, cur_vals, stages = _push_naive(
             gidx, vals=values, op=op, expected=expected,
             axis=shard_axes, n_shards=n_shards, m_loc=m_loc,
             m_global=m_global, need_fetched=need_fetched,
             reverse=reverse_ranks)
     elif strategy == "oneshot" or len(shard_axes) == 1:
-        dest = owner_shard(cur_idx, m_loc, n_shards)
-        cap = min(n, m_loc)
         stage, cur_idx, cur_vals = _push(
-            cur_idx, cur_vals, op, expected, axis=shard_axes,
-            n_dest=n_shards, dest=dest, cap=cap, m_global=m_global,
-            need_fetched=need_fetched, backend=backend, spec=spec,
-            reverse=reverse_ranks)
+            gidx, values, op, expected, axis=shard_axes, n_dest=n_shards,
+            route=to_owner(n_shards, m_global), cap=min(n, m_loc),
+            bound=m_global, next_bound=m_loc, need_fetched=need_fetched,
+            backend=backend, spec=spec, reverse=reverse_ranks)
         stages.append(stage)
     else:  # hierarchical: inner axes to the deputy, outer axis to the owner
         inner = shard_axes[1:]
         n_inner = math.prod(sizes[1:])
         n_outer = sizes[0]
-        dest1 = owner_shard(cur_idx, m_loc, n_shards) % n_inner
-        cap1 = min(n, m_loc * n_outer)
+        m_pod = m_loc * n_outer         # slots one deputy gathers for
+
+        def to_deputy(k):               # (inner rank, (outer, row) key)
+            owner = owner_shard(k, m_loc, n_shards)
+            row = local_row(k, owner, m_loc, m_global)
+            outer = (owner // n_inner).astype(k.dtype)
+            return owner % n_inner, outer * m_loc + row.astype(k.dtype)
+
+        cap1 = min(n, m_pod)
         stage, cur_idx, cur_vals = _push(
-            cur_idx, cur_vals, op, expected, axis=inner, n_dest=n_inner,
-            dest=dest1, cap=cap1, m_global=m_global,
+            gidx, values, op, expected, axis=inner, n_dest=n_inner,
+            route=to_deputy, cap=cap1, bound=m_global, next_bound=m_pod,
             need_fetched=need_fetched, backend=backend, spec=spec,
             reverse=reverse_ranks)
         stages.append(stage)
-        dest2 = owner_shard(cur_idx, m_loc * n_inner, n_outer)
-        cap2 = min(n_inner * cap1, m_loc)
         stage, cur_idx, cur_vals = _push(
             cur_idx, cur_vals, op, expected, axis=shard_axes[0],
-            n_dest=n_outer, dest=dest2, cap=cap2, m_global=m_global,
+            n_dest=n_outer, route=to_owner(n_outer, m_pod),
+            cap=min(n_inner * cap1, m_loc), bound=m_pod, next_bound=m_loc,
             need_fetched=need_fetched, backend=backend, spec=spec,
             reverse=reverse_ranks)
         stages.append(stage)
 
     if rep_axes:  # serialize replica groups at replica rank 0
-        dest_r = jnp.zeros(cur_idx.shape, jnp.int32)
-        cap_r = min(int(cur_idx.shape[0]), m_loc)
         stage, cur_idx, cur_vals = _push(
             cur_idx, cur_vals, op, expected, axis=rep_axes, n_dest=n_rep,
-            dest=dest_r, cap=cap_r, m_global=m_global,
-            need_fetched=need_fetched, backend=backend, spec=spec,
-            reverse=reverse_ranks)
+            route=lambda k: (jnp.zeros(k.shape, jnp.int32), k),
+            cap=min(int(cur_idx.shape[0]), m_loc), bound=m_loc,
+            next_bound=m_loc, need_fetched=need_fetched, backend=backend,
+            spec=spec, reverse=reverse_ranks)
         stages.append(stage)
 
-    # --- resolve at the owner ---------------------------------------------
+    # --- resolve at the owner: cur_idx are its rows, m_loc the scratch ----
     with jax.named_scope("exchange.resolve"):
-        shard = jax.lax.axis_index(shard_axes)
-        row = local_row(cur_idx, shard, m_loc, m_global)
         res = rmw_engine.execute_backend(
-            table, row, cur_vals, op,
+            table, cur_idx, cur_vals, op,
             None if op != "cas" else jnp.asarray(expected, table.dtype),
             backend=backend, spec=spec, need_fetched=need_fetched)
         new_table = res.table
@@ -545,7 +606,7 @@ def execute_sharded(table: Array, indices: Array, values: Array, op: str,
     stats = None
     if collect_stats:
         level_in, level_out = _stage_level_counts(
-            stages, m_global, shard_axes + rep_axes)
+            stages, shard_axes + rep_axes)
         stats = _contention_stats(
             gidx, m_loc=m_loc, m_global=m_global, shard_axes=shard_axes,
             rep_axes=rep_axes, level_in=level_in, level_out=level_out)
@@ -575,11 +636,10 @@ def _push_naive(gidx, vals, op, expected, axis, n_shards, m_loc, m_global,
     cap = n
     with jax.named_scope("exchange.precombine"):    # packing only
         dest = owner_shard(gidx, m_loc, n_shards)
-        valid = gidx < m_global
+        row = local_row(gidx, dest, m_loc, m_global)
         scratch = n_shards * cap
-        slotpos = _rank_slotpos(dest, valid, n_shards, cap)
-        send_idx = _scatter_padded(m_global, jnp.int32, slotpos, gidx,
-                                   scratch)
+        slotpos = _rank_slotpos(dest, row < m_loc, n_shards, cap)
+        send_idx = _scatter_padded(m_loc, jnp.int32, slotpos, row, scratch)
         send_val = _scatter_padded(0, vals.dtype, slotpos, vals, scratch)
     with jax.named_scope("exchange.send"):
         recv_idx, recv_val = _route_pair(send_idx, send_val, axis, n_shards,
@@ -587,7 +647,7 @@ def _push_naive(gidx, vals, op, expected, axis, n_shards, m_loc, m_global,
         if reverse:
             recv_idx = _flip_lanes(recv_idx, n_shards, cap)
             recv_val = _flip_lanes(recv_val, n_shards, cap)
-    comb = _Combined(order=jnp.arange(n), inv=jnp.arange(n), sidx=gidx,
+    comb = _Combined(inv=jnp.arange(n), sidx=gidx,
                      sval=vals, seg_start=jnp.ones((n,), bool),
                      seg_id=jnp.arange(n, dtype=jnp.int32),
                      combined=vals,
@@ -595,7 +655,7 @@ def _push_naive(gidx, vals, op, expected, axis, n_shards, m_loc, m_global,
                          op, vals.dtype, expected), vals.dtype),
                      loc_success=jnp.ones((n,), bool))
     stage = _Stage(axis=axis, n_dest=n_shards, cap=cap, comb=comb,
-                   slotpos=slotpos, m_global=m_global, reverse=reverse)
+                   slotpos=slotpos, bound=m_global, reverse=reverse)
     return recv_idx, recv_val, [stage]
 
 
@@ -879,7 +939,7 @@ def cost_exchange_dense(spec, op: str, n: int, m_global: int,
                         need_fetched: bool = True,
                         distinct_slots: Optional[int] = None) -> float:
     del distinct_slots              # dense path always moves the full table
-    if op != "faa" or need_fetched:
+    if op != "faa" or need_fetched or m_global > INT32_SLOTS:
         return float("inf")
     gather = spec.gather_elem_s or 2e-9
     return (n + m_global) * gather + _rs_s(spec, 4 * m_global, axes)
